@@ -810,12 +810,15 @@ def bench_scheduler(smoke: bool = False, out_path: str = None):
     # the zero-collective proof for the pool's compiled gather/scatter:
     # run the HLO scan in a subprocess (it forces an 8-device CPU
     # topology via XLA_FLAGS, which must never leak into this process)
-    # and record the count next to the rows it certifies
+    # and record the count next to the rows it certifies. The child is
+    # held to the CPU: this process may own the chip, and a second
+    # process reaching for it would fail or hang
     import subprocess
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "slot_pool_check.py")],
         capture_output=True, text=True,
-        env=dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src")))
+        env=dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+                 JAX_PLATFORMS="cpu"))
     pool_ok = (proc.returncode == 0
                and "SLOT-POOL OK collectives=0" in proc.stdout)
     slot_pool_check = {"ok": bool(pool_ok),
@@ -1699,6 +1702,8 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="output path for suites that write a BENCH json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     pick = args.suite or args.only
     for name, fn in SECTIONS.items():
         if pick and name != pick:

@@ -37,12 +37,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
     q = q_ref[0, 0]  # (1, hd)
     k = k_ref[0, 0]  # (bk, hd)
     v = v_ref[0, 0]  # (bk, hd)
-    bias = bias_ref[0]  # (bk,)
+    bias = bias_ref[0]  # (1, bk)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # (1, bk)
-    s = s + bias[None, :]
+    s = s + bias
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -63,7 +63,11 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
 def decode_attention_bhd(q, k, v, bias, *, scale: float | None = None,
                          block_k: int = 512, interpret: bool = False):
     """q (B, nq, 1, hd); k/v (B, nkv, W, hd); bias (B, W) f32 additive
-    (0 = attendable, NEG_INF = masked). Returns (B, nq, 1, hd)."""
+    (0 = attendable, NEG_INF = masked). Returns (B, nq, 1, hd).
+
+    The bias travels as (B, 1, W) so its block is (1, block_k) in the last
+    two dims — full sublane extent, lane-aligned — which the TPU's block
+    tiling rule accepts; a (1, block_k) block of a (B, W) array is not."""
     b, nq, one, hd = q.shape
     nkv, w = k.shape[1], k.shape[2]
     g = nq // nkv
@@ -80,7 +84,7 @@ def decode_attention_bhd(q, k, v, bias, *, scale: float | None = None,
             pl.BlockSpec((1, 1, 1, hd), lambda bb, h, ki: (bb, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda bb, h, ki, g=g: (bb, h // g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda bb, h, ki, g=g: (bb, h // g, ki, 0)),
-            pl.BlockSpec((1, block_k), lambda bb, h, ki: (bb, ki)),
+            pl.BlockSpec((1, 1, block_k), lambda bb, h, ki: (bb, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, hd), lambda bb, h, ki: (bb, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nq, 1, hd), q.dtype),
@@ -90,4 +94,4 @@ def decode_attention_bhd(q, k, v, bias, *, scale: float | None = None,
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, bias)
+    )(q, k, v, bias[:, None, :])

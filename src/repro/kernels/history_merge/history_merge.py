@@ -6,9 +6,9 @@ event buffer (seconds-fresh, short window) into one model-ready history —
 time-ordered, deduplicated by item (freshest occurrence wins, real-time
 beats batch on ties), truncated to the feature length K.
 
-TPU adaptation (DESIGN.md §2): no data-dependent shapes and no sort
-primitive. Both inputs arrive as fixed-size padded buffers with validity
-flags; the merge is reformulated as **pairwise rank computation**:
+TPU adaptation: no data-dependent shapes and no sort primitive. Both inputs
+arrive as fixed-size padded buffers with validity flags; the merge is
+reformulated as **pairwise rank computation**:
 
   rank(i)  = #{ j valid, non-duplicate : j strictly fresher than i }
   slot(i)  = K - 1 - rank(i)            (right-aligned, ascending time)
@@ -17,9 +17,17 @@ flags; the merge is reformulated as **pairwise rank computation**:
 over the concatenated N = L_batch + L_rt events — O(N²) boolean work on
 (N, N) tiles, fully vectorized (VPU), followed by a one-hot (N, K) scatter
 expressed as a masked integer reduction. N ≈ a few hundred, so N² ≈ 10⁵
-lane-ops per request — microseconds, vs. a host round-trip for a dynamic
-merge. Grid = (batch,); each step's working set is O(N² + N·K) int32/bool
-in VMEM (≈ 0.6 MB at N=320, K=256).
+lane-ops per request. Grid = (batch,).
+
+Layout: Mosaic works on 2-D (sublane, lane) tiles, so the body never
+concatenates or reshapes 1-D rows. The wrapper concatenates the two
+buffers in XLA, pads N up to a lane multiple with invalid (inert) events,
+and hands every event plane to the kernel twice — as a (1, N) row and as
+an (N, 1) column. Pairwise planes are then plain broadcasts:
+``F[j, i]`` = "event j is fresher than event i" with j on sublanes and i
+on lanes, and its transpose is ``G = ~(F | eye)`` (a strict total order),
+so every per-event quantity is a sublane or a lane reduction and no
+transpose is ever needed.
 
 Freshness total order: (ts, is_rt, buffer index) lexicographic.
 """
@@ -31,38 +39,45 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
 
-def _merge_kernel(bi_ref, bt_ref, bv_ref, ri_ref, rt_ref, rv_ref,
-                  oi_ref, ot_ref, ov_ref, *, lb: int, lr: int, k: int):
-    n = lb + lr
-    items = jnp.concatenate([bi_ref[0], ri_ref[0]])  # (N,)
-    ts = jnp.concatenate([bt_ref[0], rt_ref[0]])
-    valid = jnp.concatenate([bv_ref[0], rv_ref[0]]) > 0
-    is_rt = jax.lax.iota(jnp.int32, n) >= lb
-    idx = jax.lax.iota(jnp.int32, n)
 
-    # fresher(j, i): event j strictly fresher than event i (lexicographic)
-    ts_j, ts_i = ts[:, None], ts[None, :]
-    rt_j, rt_i = is_rt[:, None], is_rt[None, :]
-    ix_j, ix_i = idx[:, None], idx[None, :]
-    fresher = (ts_j > ts_i) | (
-        (ts_j == ts_i) & ((rt_j & ~rt_i) | ((rt_j == rt_i) & (ix_j > ix_i))))
+def _merge_kernel(ir_ref, tr_ref, vr_ref, ic_ref, tc_ref, vc_ref,
+                  oi_ref, ot_ref, ov_ref, *, lb: int, k: int):
+    n = ir_ref.shape[-1]
+    items_r, ts_r, valid_r = ir_ref[0], tr_ref[0], vr_ref[0] > 0  # (1, N)
+    items_c, ts_c, valid_c = ic_ref[0], tc_ref[0], vc_ref[0] > 0  # (N, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)  # j
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)  # i
+    rt_j, rt_i = row >= lb, col >= lb
 
-    valid_j = valid[:, None]
-    same_item = items[:, None] == items[None, :]
-    dup = jnp.any(valid_j & same_item & fresher, axis=0) | ~valid  # (N,)
+    # F[j, i]: event j strictly fresher than event i (lexicographic)
+    fresher = (ts_c > ts_r) | ((ts_c == ts_r) & (
+        (rt_j & ~rt_i) | ((rt_j == rt_i) & (row > col))))
+    # G[j, i] = F[i, j]: the order is total and strict
+    staler = ~(fresher | (row == col))
+    same_item = items_c == items_r
 
-    alive_j = (valid & ~dup)[:, None]
-    rank = jnp.sum((alive_j & fresher).astype(jnp.int32), axis=0)  # (N,)
-    keep = valid & ~dup & (rank < k)
-    slot = k - 1 - rank  # right-aligned: rank 0 (freshest) -> slot K-1
+    one, zero = jnp.int32(1), jnp.int32(0)
+    # dup(i): a fresher valid event of the same item exists
+    dup_r = jnp.max(jnp.where(valid_c & same_item & fresher, one, zero),
+                    axis=0, keepdims=True) > 0                    # (1, N)
+    dup_c = jnp.max(jnp.where(valid_r & same_item & staler, one, zero),
+                    axis=1, keepdims=True) > 0                    # (N, 1)
+    alive_r = valid_r & ~dup_r
+    rank_c = jnp.sum(jnp.where(alive_r & staler, one, zero),
+                     axis=1, keepdims=True)                       # (N, 1)
+    keep_c = valid_c & ~dup_c & (rank_c < k)
+    slot_c = k - 1 - rank_c  # right-aligned: rank 0 (freshest) -> slot K-1
 
-    # one-hot scatter as a masked reduction over N (no dynamic indexing)
-    slots = jax.lax.iota(jnp.int32, k)[None, :]  # (1, K)
-    onehot = keep[:, None] & (slot[:, None] == slots)  # (N, K)
-    oi_ref[0] = jnp.sum(jnp.where(onehot, items[:, None], 0), axis=0)
-    ot_ref[0] = jnp.sum(jnp.where(onehot, ts[:, None], 0), axis=0)
-    ov_ref[0] = jnp.sum(onehot.astype(jnp.int32), axis=0)
+    # one-hot scatter as a masked sublane reduction (no dynamic indexing)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (n, k), 1)
+    onehot = keep_c & (slot_c == slots)                           # (N, K)
+    oi_ref[0] = jnp.sum(jnp.where(onehot, items_c, zero), axis=0,
+                        keepdims=True)
+    ot_ref[0] = jnp.sum(jnp.where(onehot, ts_c, zero), axis=0,
+                        keepdims=True)
+    ov_ref[0] = jnp.sum(jnp.where(onehot, one, zero), axis=0, keepdims=True)
 
 
 def history_merge_pallas(batch_items, batch_ts, batch_valid,
@@ -74,23 +89,26 @@ def history_merge_pallas(batch_items, batch_ts, batch_valid,
     b, lb = batch_items.shape
     lr = rt_items.shape[1]
     k = out_len
+    n = max(LANES, -(-(lb + lr) // LANES) * LANES)
 
-    # A zero-length side (empty realtime buffer / empty batch window) would
-    # give a zero-width BlockSpec, which pallas rejects; widen it to one
-    # all-invalid column — the validity flags make the extra event inert.
-    if lb == 0:
-        z = jnp.zeros((b, 1), jnp.int32)
-        batch_items, batch_ts, batch_valid, lb = z, z, z, 1
-    if lr == 0:
-        z = jnp.zeros((b, 1), jnp.int32)
-        rt_items, rt_ts, rt_valid, lr = z, z, z, 1
+    def events(bx, rx):
+        # padded tail = invalid events: never kept, never anyone's dup
+        x = jnp.concatenate([bx, rx], axis=1).astype(jnp.int32)
+        return jnp.pad(x, ((0, 0), (0, n - lb - lr)))
 
-    row = lambda L: pl.BlockSpec((1, L), lambda bb: (bb, 0))
-    return pl.pallas_call(
-        functools.partial(_merge_kernel, lb=lb, lr=lr, k=k),
+    planes = [events(batch_items, rt_items), events(batch_ts, rt_ts),
+              events(batch_valid, rt_valid)]
+    rows = [p[:, None, :] for p in planes]   # (B, 1, N)
+    cols = [p[:, :, None] for p in planes]   # (B, N, 1)
+    row_spec = pl.BlockSpec((1, 1, n), lambda bb: (bb, 0, 0))
+    col_spec = pl.BlockSpec((1, n, 1), lambda bb: (bb, 0, 0))
+    out_spec = pl.BlockSpec((1, 1, k), lambda bb: (bb, 0, 0))
+    outs = pl.pallas_call(
+        functools.partial(_merge_kernel, lb=lb, k=k),
         grid=(b,),
-        in_specs=[row(lb), row(lb), row(lb), row(lr), row(lr), row(lr)],
-        out_specs=[row(k), row(k), row(k)],
-        out_shape=[jax.ShapeDtypeStruct((b, k), jnp.int32)] * 3,
+        in_specs=[row_spec] * 3 + [col_spec] * 3,
+        out_specs=[out_spec] * 3,
+        out_shape=[jax.ShapeDtypeStruct((b, 1, k), jnp.int32)] * 3,
         interpret=interpret,
-    )(batch_items, batch_ts, batch_valid, rt_items, rt_ts, rt_valid)
+    )(*rows, *cols)
+    return tuple(o[:, 0, :] for o in outs)

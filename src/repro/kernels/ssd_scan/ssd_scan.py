@@ -2,7 +2,7 @@
 
 Grid = (batch, n_head_blocks, n_chunks) with the chunk axis sequential; the
 per-(batch, head-block) recurrent state (bh, hp, ds) f32 lives in VMEM
-scratch and is carried across chunk steps. Each step computes:
+scratch and is carried across chunk steps. Each step computes, per head:
 
   intra-chunk:  y_ij = Σ_{j<=i} exp(Acum_i - Acum_j) (C_i·B_j) dt_j x_j
   inter-chunk:  y_i += C_i · (exp(Acum_i) * state_in)
@@ -12,9 +12,19 @@ which is exactly the discrete SSD form of [arXiv:2405.21060] — the
 quadratic intra-chunk term maps onto the MXU (chunk×chunk matmuls) while
 the O(S) state pass stays in VMEM, never round-tripping HBM.
 
-VMEM at (Q=256, bh=8, hp=64, ds=128): x block 256·8·64·4 ≈ 0.5 MB, the
-L/segsum tensor 8·256·256·4 ≈ 2 MB, state 8·64·128·4 ≈ 0.25 MB — ~4 MB
-total with B/C blocks, inside the VMEM budget with double buffering.
+Layout: Mosaic works on 2-D (sublane, lane) tiles, and a block's last two
+dims must be (8, 128)-aligned or whole. The wrapper therefore moves heads
+ahead of the sequence (x, y as (b, nh, s, hp); dt as (b, nh, s)), keeps
+the per-head scalars A and D whole in SMEM, and also passes dt as
+(b, s, nh) with the whole head axis in one lane block. The body loops
+over the block's heads so every operation is 2-D: dt's row form comes
+from the head-major copy, its column form from a one-hot lane reduction
+of the sequence-major copy, and the chunk cumsum in each form is a masked
+sublane or lane reduction — no transposes, no 3-D contractions.
+
+VMEM at (Q=256, bh=8, hp=64, ds=128): x and y blocks 8·256·128·4 ≈ 1 MB
+each (hp lane-padded), state 8·64·128·4 ≈ 0.25 MB, a few (Q, Q) f32
+temporaries of 0.25 MB per head — ~8 MB with double buffering.
 """
 from __future__ import annotations
 
@@ -26,8 +36,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
-                y_ref, hout_ref, state_scr, *, chunk: int):
+def _ssd_kernel(x_ref, dtr_ref, dts_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
+                y_ref, hout_ref, state_scr, *, chunk: int, bh: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -35,39 +46,54 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
     def _init():
         state_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)     # (Q, bh, hp)
-    dt = dt_ref[0].astype(jnp.float32)   # (Q, bh)
-    A = a_ref[...].astype(jnp.float32)   # (bh,)
+    q = chunk
     B = b_ref[0].astype(jnp.float32)     # (Q, ds)
     C = c_ref[0].astype(jnp.float32)     # (Q, ds)
-    D = d_ref[...].astype(jnp.float32)   # (bh,)
-
-    dA = dt * A[None, :]                 # (Q, bh)
-    cum = jnp.cumsum(dA, axis=0)         # (Q, bh)
-
-    # intra-chunk quadratic term
-    seg = cum[:, None, :] - cum[None, :, :]          # (Q, Q, bh)
-    tril = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tril[:, :, None], jnp.exp(seg), 0.0)  # (Q, Q, bh)
+    dts = dts_ref[0].astype(jnp.float32)  # (Q, nh) — every head's dt
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    tril = row >= col
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, dts.shape[1]), 1)
     scores = jax.lax.dot_general(
         C, B, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)             # (Q, Q)
-    M = scores[:, :, None] * L                          # (Q, Q, bh)
-    xdt = x * dt[:, :, None]                            # (Q, bh, hp)
-    y = jnp.einsum("ijh,jhp->ihp", M, xdt)
+        preferred_element_type=jnp.float32)             # (Q, Q) = C·Bᵀ
 
-    # inter-chunk: contribution of the state entering this chunk
-    state_in = state_scr[...]                           # (bh, hp, ds)
-    y += jnp.einsum("is,ih,hps->ihp", C, jnp.exp(cum), state_in)
+    for h in range(bh):
+        a = a_ref[hi * bh + h].astype(jnp.float32)      # SMEM scalar
+        d = d_ref[hi * bh + h].astype(jnp.float32)
+        x = x_ref[0, h].astype(jnp.float32)             # (Q, hp)
+        dt_r = dtr_ref[0, h:h + 1, :].astype(jnp.float32)  # (1, Q)
+        dt_c = jnp.sum(jnp.where(lane_head == hi * bh + h, dts, 0.0),
+                       axis=1, keepdims=True)           # (Q, 1)
+        da_r, da_c = dt_r * a, dt_c * a
+        # inclusive chunk cumsum, as a column and as a row
+        cum_c = jnp.sum(jnp.where(tril, da_r, 0.0), axis=1, keepdims=True)
+        cum_r = jnp.sum(jnp.where(row <= col, da_c, 0.0), axis=0,
+                        keepdims=True)
+        # chunk total, broadcast over lanes first: Mosaic broadcasts a
+        # (1, 1) value along one tiled axis at a time
+        cum_last = jnp.broadcast_to(jnp.sum(da_r, axis=1, keepdims=True),
+                                    (1, state_scr.shape[-1]))  # (1, ds)
 
-    y += D[None, :, None] * x
-    y_ref[0] = y.astype(y_ref.dtype)
+        # intra-chunk quadratic term
+        L = jnp.where(tril, jnp.exp(cum_c - cum_r), 0.0)  # (Q, Q)
+        xdt = x * dt_c                                     # (Q, hp)
+        y = jnp.dot(scores * L, xdt, preferred_element_type=jnp.float32)
 
-    # state update
-    decay_to_end = jnp.exp(cum[-1:, :] - cum)           # (Q, bh)
-    upd = jnp.einsum("js,jhp->hps", B, xdt * decay_to_end[:, :, None])
-    state_scr[...] = jnp.exp(cum[-1])[:, None, None] * state_in + upd
+        # inter-chunk: contribution of the state entering this chunk
+        state_in = state_scr[h]                            # (hp, ds)
+        y += jnp.exp(cum_c) * jax.lax.dot_general(
+            C, state_in, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (Q, hp)
+        y += d * x
+        y_ref[0, h] = y.astype(y_ref.dtype)
+
+        # state update
+        xdec = xdt * jnp.exp(cum_last[:, :1] - cum_c)      # (Q, hp)
+        upd = jax.lax.dot_general(
+            xdec, B, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (hp, ds)
+        state_scr[h] = jnp.exp(cum_last) * state_in + upd
 
     @pl.when(ci == nc - 1)
     def _finalize():
@@ -87,26 +113,28 @@ def ssd_scan_pallas(x, dt, A, B, C, D, h0, *, chunk: int = 256,
 
     grid = (b, nh // bh, s // chunk)
     y, hout = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=chunk),
+        functools.partial(_ssd_kernel, chunk=chunk, bh=bh),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, bh, hp), lambda bb, hi, ci: (bb, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, bh), lambda bb, hi, ci: (bb, ci, hi)),
-            pl.BlockSpec((bh,), lambda bb, hi, ci: (hi,)),
+            pl.BlockSpec((1, bh, chunk, hp), lambda bb, hi, ci: (bb, hi, ci, 0)),
+            pl.BlockSpec((1, bh, chunk), lambda bb, hi, ci: (bb, hi, ci)),
+            pl.BlockSpec((1, chunk, nh), lambda bb, hi, ci: (bb, ci, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, ds), lambda bb, hi, ci: (bb, ci, 0)),
             pl.BlockSpec((1, chunk, ds), lambda bb, hi, ci: (bb, ci, 0)),
-            pl.BlockSpec((bh,), lambda bb, hi, ci: (hi,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bh, hp, ds), lambda bb, hi, ci: (bb, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, bh, hp), lambda bb, hi, ci: (bb, ci, hi, 0)),
+            pl.BlockSpec((1, bh, chunk, hp), lambda bb, hi, ci: (bb, hi, ci, 0)),
             pl.BlockSpec((1, bh, hp, ds), lambda bb, hi, ci: (bb, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, nh, hp), x.dtype),
+            jax.ShapeDtypeStruct((b, nh, s, hp), x.dtype),
             jax.ShapeDtypeStruct((b, nh, hp, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bh, hp, ds), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, B, C, D, h0)
-    return y, hout
+    )(jnp.moveaxis(x, 2, 1), jnp.moveaxis(dt, 2, 1), dt,
+      A.astype(jnp.float32), B, C, D.astype(jnp.float32), h0)
+    return jnp.moveaxis(y, 1, 2), hout

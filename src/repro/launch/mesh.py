@@ -41,7 +41,8 @@ def make_serving_mesh(data: int = 1, model: int = 1,
         raise ValueError(
             f"mesh {data}x{model} needs {n} devices, have {len(devices)}; "
             f"on CPU, set XLA_FLAGS={host_device_flags(n)!r} before the "
-            f"first jax use (launch/serve.py --mesh does this for you)")
+            f"first jax use (launch/serve.py --mesh does this for you "
+            f"under JAX_PLATFORMS=cpu)")
     return jax.sharding.Mesh(
         np.asarray(devices[:n]).reshape(data, model), ("data", "model"))
 

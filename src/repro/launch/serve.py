@@ -35,12 +35,14 @@ arrival immediately in a padded partial pane — the latency floor):
 
 ``--mesh data,model`` runs either mode **sharded**: the engine jits with
 NamedSharding in/out specs over a ("data", "model") mesh and request
-panes split over the data axis (``--batch`` must divide it). On CPU the
-launcher reuses the dry-run's forced-host-device XLA trick so e.g.
-``--mesh 8,1 --batch 16`` is runnable (and CI-testable) on one machine:
+panes split over the data axis (``--batch`` must divide it). With
+``JAX_PLATFORMS=cpu`` the launcher reuses the dry-run's forced-host-device
+XLA trick so e.g. ``--mesh 8,1 --batch 16`` is runnable (and CI-testable)
+on one machine; on any other platform the mesh takes real devices:
 
-  PYTHONPATH=src python -m repro.launch.serve --arch mamba2-780m --reduced \
-      --loop --mesh 8,1 --batch 16 --users 500 --rounds 4
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --arch mamba2-780m --reduced --loop --mesh 8,1 --batch 16 \
+      --users 500 --rounds 4
 """
 from __future__ import annotations
 
@@ -273,10 +275,11 @@ def main() -> None:
         if len(mesh_shape) != 2:
             raise SystemExit("--mesh wants two sizes: data,model")
         n = mesh_shape[0] * mesh_shape[1]
-        plat = os.environ.get("JAX_PLATFORMS", "")
-        if n > 1 and (not plat or "cpu" in plat):
+        if n > 1 and os.environ.get("JAX_PLATFORMS") == "cpu":
             # the dry-run trick: simulate the mesh's devices on one CPU
-            # host (must land in XLA_FLAGS before jax first initializes)
+            # host (must land in XLA_FLAGS before jax first initializes).
+            # Only when the CPU is asked for explicitly: a chip host whose
+            # TPU failed to initialise must not silently serve on fakes
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "") +
                 f" --xla_force_host_platform_device_count={n}")
@@ -285,10 +288,12 @@ def main() -> None:
     import jax.numpy as jnp
 
     from repro.configs.base import get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_serving_mesh
     from repro.models.model import init_params
     from repro.serving.engine import ServingConfig, ServingEngine
 
+    enable_compile_cache()
     mesh = None
     if mesh_shape is not None:
         mesh = make_serving_mesh(*mesh_shape)

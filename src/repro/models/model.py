@@ -207,7 +207,6 @@ def _embed(params, cfg, tokens, prefix_embeds, embed_mesh=None):
         # XLA's own gather partitioning mis-compiles this pattern inside
         # scanned/remat bodies (hlo-verifier failure), so we don't let it
         # guess. Grad: shard_map transposes to a local scatter-add + psum.
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as PS
         dp = tuple(a for a in ("pod", "data") if a in embed_mesh.axis_names)
         dpn = 1
@@ -216,7 +215,7 @@ def _embed(params, cfg, tokens, prefix_embeds, embed_mesh=None):
         bspec = dp if tokens.shape[0] % dpn == 0 else None
         tpn = embed_mesh.shape.get("model", 1)
         dspec = "model" if cfg.d_model % tpn == 0 else None
-        x = shard_map(
+        x = jax.shard_map(
             lambda tbl, tok: tbl[tok], mesh=embed_mesh,
             in_specs=(PS(None, dspec), PS(bspec, None)),
             out_specs=PS(bspec, None, dspec))(table, tokens)

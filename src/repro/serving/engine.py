@@ -366,9 +366,12 @@ def _inject_impl(params, caches, tokens, valid, prefix_valid, start,
     # is meaningless — callers gate on n_valid > 0). Selected by one-hot
     # contraction, not logits[rows, idx]: a batch-dependent gather makes
     # GSPMD all-gather the whole (B,Ss,V) logits across the data axis.
+    # HIGHEST keeps the selection an exact copy on the TPU, whose default
+    # float32 matmul is one bfloat16 pass.
     sel = (jnp.arange(logits.shape[1], dtype=jnp.int32)[None, :]
            == jnp.maximum(n_valid - 1, 0)[:, None])
-    last_valid = jnp.einsum("bs,bsv->bv", sel.astype(logits.dtype), logits)
+    last_valid = jnp.einsum("bs,bsv->bv", sel.astype(logits.dtype), logits,
+                            precision=jax.lax.Precision.HIGHEST)
     out = {
         "caches": caches, "logits": logits,
         "valid": jnp.concatenate([prefix_valid, valid], axis=1),

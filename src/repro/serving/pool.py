@@ -27,7 +27,9 @@ one-hot form everywhere (fixed shapes for any writeback width, and it
 only runs on admissions).
 
 Bitwise exactness: multiplying by 0/1 and adding 0 is exact in every
-float dtype, and integer/bool leaves contract in int32 — a gathered row
+float dtype at full precision (the contractions ask for
+``Precision.HIGHEST``: the TPU's default float32 matmul is one bfloat16
+pass), and integer/bool leaves contract in int32 — a gathered row
 is bit-identical to the slot contents, and a scattered slot is
 bit-identical to the pane row. The pooled serving path therefore serves
 slates bitwise equal to the host-LRU path (property-tested in
@@ -64,6 +66,12 @@ from repro.serving.engine import ServingEngine
 # One-hot gather/scatter jit bodies
 # ----------------------------------------------------------------------
 
+# The copies below are contractions with 0/1 weights, exact only at full
+# float32 precision: at DEFAULT precision the TPU runs a float32 matmul as
+# one bfloat16 pass, which rounds every copied value to 8 mantissa bits.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _sel(leaf, onehot, slot_axis: int):
     """Gather pane rows from a pool leaf: one-hot einsum over the slot
     axis (axis 1 for cache leaves, axis 0 for the flat planes). Bool and
@@ -73,9 +81,9 @@ def _sel(leaf, onehot, slot_axis: int):
     work = leaf.astype(jnp.int32) if cast else leaf
     w = onehot.astype(work.dtype)
     if slot_axis == 1:
-        out = jnp.einsum("bn,rn...->rb...", w, work)
+        out = jnp.einsum("bn,rn...->rb...", w, work, precision=_EXACT)
     else:
-        out = jnp.einsum("bn,n...->b...", w, work)
+        out = jnp.einsum("bn,n...->b...", w, work, precision=_EXACT)
     return out.astype(dt) if cast else out
 
 
@@ -90,10 +98,10 @@ def _upd(pool_leaf, rows_leaf, onehot, covered, slot_axis: int):
     w = onehot.astype(pl.dtype)
     keep = (1 - covered).astype(pl.dtype)
     if slot_axis == 1:
-        contrib = jnp.einsum("bn,rb...->rn...", w, rl)
+        contrib = jnp.einsum("bn,rb...->rn...", w, rl, precision=_EXACT)
         keep = keep.reshape((1, -1) + (1,) * (pl.ndim - 2))
     else:
-        contrib = jnp.einsum("bn,b...->n...", w, rl)
+        contrib = jnp.einsum("bn,b...->n...", w, rl, precision=_EXACT)
         keep = keep.reshape((-1,) + (1,) * (pl.ndim - 1))
     out = pl * keep + contrib
     return out.astype(dt) if cast else out

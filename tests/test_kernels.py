@@ -1,8 +1,11 @@
 """Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles.
 
-All kernels run in interpret mode (the kernel body executes in Python on
-CPU); the TPU lowering is exercised structurally via pl.pallas_call +
-BlockSpec construction.
+All kernels run in interpret mode: the kernel body executes in Python on
+the CPU. That checks the math and the grid/block decomposition, but it
+never lowers the kernel for the TPU, so it cannot see a block the chip's
+tiling rule refuses or a body Mosaic cannot compile;
+tests/test_tpu_compile.py compiles every kernel for a described TPU v5e
+for that.
 """
 import jax
 import jax.numpy as jnp

@@ -44,13 +44,6 @@ _SCFG = ServingConfig(max_batch=4, prefill_len=32, inject_len=8,
                       cache_capacity=64)
 
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:  # jax <= 0.4.x signature
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def _server(mesh=None, cache_bytes=None, use_cache=True):
     store = BatchFeatureStore(FeatureStoreConfig(
         n_users=N_USERS, feature_len=24))
@@ -98,7 +91,7 @@ def test_mesh_1x1_bitwise_equals_plain_engine():
 
 
 def test_mesh_engine_rejects_uneven_batch():
-    mesh = _abstract_mesh((8, 1), ("data", "model"))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     with pytest.raises(ValueError, match="multiple of the data-axis"):
         serving_pspecs(_CFG, mesh, max_batch=6)
 
@@ -107,7 +100,7 @@ def test_serving_params_replicated_over_data():
     """Serving replicates weights across data-parallel replicas: no param
     spec may reference the data axis (FSDP is stripped), while cache and
     token specs must shard their batch dim over it."""
-    mesh = _abstract_mesh((8, 2), ("data", "model"))
+    mesh = AbstractMesh((8, 2), ("data", "model"))
     sp = serving_pspecs(_CFG, mesh, max_batch=16)
 
     def axes_of(spec):
@@ -132,7 +125,7 @@ def test_serving_params_replicated_over_data():
 def test_seq_cache_specs_match_prefill_tree(arch_cfg):
     """seq_cache_pspecs must mirror the exact pytree prefill returns —
     a structure mismatch would fail deep inside jit out_shardings."""
-    mesh = _abstract_mesh((2, 1), ("data", "model"))
+    mesh = AbstractMesh((2, 1), ("data", "model"))
     specs = seq_cache_pspecs(arch_cfg, mesh, batch=4)
     shapes = param_shapes(arch_cfg, dtype=jnp.float32)
     toks = jax.ShapeDtypeStruct((4, 16), jnp.int32)

@@ -10,17 +10,8 @@ from repro.models.model import cache_shapes, param_shapes
 from repro.sharding.rules import (ShardingRules, batch_pspec, cache_pspecs,
                                   data_axes, param_pspecs)
 
-def _mesh(sizes, names):
-    """AbstractMesh across jax versions: <=0.4.x takes one shape tuple of
-    (name, size) pairs; >=0.5 takes (axis_sizes, axis_names)."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-SINGLE = _mesh((16, 16), ("data", "model"))
-MULTI = _mesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(shapes, specs, mesh):
