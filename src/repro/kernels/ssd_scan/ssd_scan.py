@@ -14,13 +14,13 @@ the O(S) state pass stays in VMEM, never round-tripping HBM.
 
 Layout: Mosaic works on 2-D (sublane, lane) tiles, and a block's last two
 dims must be (8, 128)-aligned or whole. The wrapper therefore moves heads
-ahead of the sequence (x, y as (b, nh, s, hp); dt as (b, nh, s)), keeps
-the per-head scalars A and D whole in SMEM, and also passes dt as
-(b, s, nh) with the whole head axis in one lane block. The body loops
-over the block's heads so every operation is 2-D: dt's row form comes
-from the head-major copy, its column form from a one-hot lane reduction
-of the sequence-major copy, and the chunk cumsum in each form is a masked
-sublane or lane reduction — no transposes, no 3-D contractions.
+ahead of the sequence (x, y as (b, nh, s, hp)), keeps the per-head
+scalars A and D whole in SMEM, and passes dt twice: as a row plane
+(b, nh, s) and as a column plane (b, nh, s, 1), whose last dim is whole.
+The body loops over the block's heads so every operation is 2-D: dt's
+row and column forms are read directly, and the chunk cumsum in each
+form is a masked sublane or lane reduction — no transposes, no 3-D
+contractions.
 
 VMEM at (Q=256, bh=8, hp=64, ds=128): x and y blocks 8·256·128·4 ≈ 1 MB
 each (hp lane-padded), state 8·64·128·4 ≈ 0.25 MB, a few (Q, Q) f32
@@ -36,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dtr_ref, dts_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
+def _ssd_kernel(x_ref, dtr_ref, dtc_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
                 y_ref, hout_ref, state_scr, *, chunk: int, bh: int):
     hi = pl.program_id(1)
     ci = pl.program_id(2)
@@ -49,11 +49,9 @@ def _ssd_kernel(x_ref, dtr_ref, dts_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
     q = chunk
     B = b_ref[0].astype(jnp.float32)     # (Q, ds)
     C = c_ref[0].astype(jnp.float32)     # (Q, ds)
-    dts = dts_ref[0].astype(jnp.float32)  # (Q, nh) — every head's dt
     row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     tril = row >= col
-    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, dts.shape[1]), 1)
     scores = jax.lax.dot_general(
         C, B, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)             # (Q, Q) = C·Bᵀ
@@ -63,8 +61,7 @@ def _ssd_kernel(x_ref, dtr_ref, dts_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
         d = d_ref[hi * bh + h].astype(jnp.float32)
         x = x_ref[0, h].astype(jnp.float32)             # (Q, hp)
         dt_r = dtr_ref[0, h:h + 1, :].astype(jnp.float32)  # (1, Q)
-        dt_c = jnp.sum(jnp.where(lane_head == hi * bh + h, dts, 0.0),
-                       axis=1, keepdims=True)           # (Q, 1)
+        dt_c = dtc_ref[0, h].astype(jnp.float32)        # (Q, 1)
         da_r, da_c = dt_r * a, dt_c * a
         # inclusive chunk cumsum, as a column and as a row
         cum_c = jnp.sum(jnp.where(tril, da_r, 0.0), axis=1, keepdims=True)
@@ -118,7 +115,7 @@ def ssd_scan_pallas(x, dt, A, B, C, D, h0, *, chunk: int = 256,
         in_specs=[
             pl.BlockSpec((1, bh, chunk, hp), lambda bb, hi, ci: (bb, hi, ci, 0)),
             pl.BlockSpec((1, bh, chunk), lambda bb, hi, ci: (bb, hi, ci)),
-            pl.BlockSpec((1, chunk, nh), lambda bb, hi, ci: (bb, ci, 0)),
+            pl.BlockSpec((1, bh, chunk, 1), lambda bb, hi, ci: (bb, hi, ci, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, ds), lambda bb, hi, ci: (bb, ci, 0)),
             pl.BlockSpec((1, chunk, ds), lambda bb, hi, ci: (bb, ci, 0)),
@@ -135,6 +132,7 @@ def ssd_scan_pallas(x, dt, A, B, C, D, h0, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((bh, hp, ds), jnp.float32)],
         interpret=interpret,
-    )(jnp.moveaxis(x, 2, 1), jnp.moveaxis(dt, 2, 1), dt,
+    )(jnp.moveaxis(x, 2, 1), jnp.moveaxis(dt, 2, 1),
+      jnp.moveaxis(dt, 2, 1)[..., None],
       A.astype(jnp.float32), B, C, D.astype(jnp.float32), h0)
     return jnp.moveaxis(y, 1, 2), hout

@@ -11,9 +11,13 @@ chip.
 The TPU library may be loaded by one process at a time, and the process
 keeps it until it exits. So the topology is described inside a module
 fixture -- never at import, in a ``skipif`` or in ``parametrize`` -- and
-every test of this kind lives in this one file: under several pytest
-workers only the worker that runs this file loads the library.
+every test of this kind lives in this one file, kept on one worker
+(``--dist loadfile``, or the ``xdist_group`` below under ``--dist
+loadgroup``): under several pytest workers only that worker loads the
+library. The tests skip only where no TPU compiler is installed; any
+other failure to describe the chip fails them.
 """
+import importlib.util
 import os
 import sys
 
@@ -27,18 +31,19 @@ import chip_smoke  # noqa: E402  (the checkout root holds it)
 
 KERNELS = ("history_merge", "ssd_scan", "decode_attention", "flash_attention")
 
+pytestmark = pytest.mark.xdist_group("libtpu")
+
 
 @pytest.fixture(scope="module")
 def one_chip():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) is installed")
     with pytest.MonkeyPatch.context() as mp:
         # the compiler would otherwise write its logs under /tmp
         mp.setenv("TPU_LOG_DIR", "disabled")
         from jax.experimental import topologies
-        try:
-            topo = topologies.get_topology_desc(platform="tpu",
-                                                topology_name="v5e:2x2")
-        except Exception as e:  # no TPU compiler in this installation
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
         # a compile for a described chip cannot be read back from the
         # persistent cache; keep the cache out of it
         was = jax.config.jax_enable_compilation_cache
