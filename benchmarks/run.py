@@ -1,5 +1,5 @@
 """Benchmark harness — one section per paper result/figure + kernel/serving
-microbenches and the roofline aggregation.
+microbenches.
 
   PYTHONPATH=src python -m benchmarks.run [--only SECTION]
   PYTHONPATH=src python benchmarks/run.py --suite feature_plane [--smoke]
@@ -10,7 +10,6 @@ Sections
   injection_overhead paper §III-B: history_merge op throughput
   serving_phases     prefill vs inject vs decode cost (O(suffix) claim)
   kernel_micro       Pallas-kernel oracle timings (XLA path on CPU)
-  roofline           aggregate dry-run JSONs into the §Roofline table
   feature_plane      vectorized EventLog stores vs the loop reference
                      (snapshot materialization + batched lookups at
                      1k/100k/1M users; writes BENCH_feature_plane.json)
@@ -53,7 +52,6 @@ Sections
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -1435,8 +1433,7 @@ def bench_serving_sharded(smoke: bool = False, out_path: str = None):
       1/8 slice of the wave on a dedicated device (same per-device rows
       as the dp=8 mesh, own feature-plane slice of host work) and
       reports wave_time(1 device, full wave) / wave_time(one isolated
-      shard) — the same simulate-what-the-host-can't methodology as
-      launch/dryrun.py's 512 fake devices.
+      shard) — simulating on the host what it cannot run.
     """
     print("\n== serving_sharded (data-parallel serving loop, CPU mesh) ==")
     from repro.configs.base import ModelConfig
@@ -1632,41 +1629,6 @@ def bench_serving_sharded(smoke: bool = False, out_path: str = None):
     return results
 
 
-# ----------------------------------------------------------------------
-def bench_roofline():
-    print("\n== roofline (dry-run artifacts; baseline -> optimized §Perf) ==")
-    files = sorted(glob.glob(os.path.join(ROOT, "experiments", "dryrun",
-                                          "*.json")))
-    if not files:
-        print("  [skip] run python -m repro.launch.dryrun --all first")
-        return
-    print(f"  {'arch':21s} {'shape':11s} {'mesh':16s} {'pkGiB':>6s} "
-          f"{'compute':>8s} {'memory base->opt':>19s} "
-          f"{'collective base->opt':>21s}")
-    tot = [0.0, 0.0, 0.0, 0.0]
-    for f in files:
-        r = json.load(open(f))
-        if r.get("status") != "ok":
-            continue
-        t = r["roofline"]
-        opt_f = f.replace(os.sep + "dryrun" + os.sep,
-                          os.sep + "dryrun_opt" + os.sep)
-        to = (json.load(open(opt_f))["roofline"]
-              if os.path.exists(opt_f) else t)
-        if r["mesh"] == "pod_16x16":
-            tot[0] += t["memory_s"]; tot[1] += to["memory_s"]
-            tot[2] += t["collective_s"]; tot[3] += to["collective_s"]
-        print(f"  {r['arch']:21s} {r['shape']:11s} {r['mesh']:16s} "
-              f"{r['memory']['peak_bytes_per_device']/2**30:6.2f} "
-              f"{to['compute_s']:8.2e} "
-              f"{t['memory_s']:9.2e}->{to['memory_s']:9.2e} "
-              f"{t['collective_s']:10.2e}->{to['collective_s']:10.2e}")
-    if tot[1] and tot[3]:
-        print(f"  fleet (single-pod): memory {tot[0]:.0f}->{tot[1]:.0f}s "
-              f"({tot[0]/tot[1]:.2f}x)  collective {tot[2]:.0f}->{tot[3]:.0f}s "
-              f"({tot[2]/tot[3]:.2f}x)")
-
-
 try:  # python -m benchmarks.run vs python benchmarks/run.py
     from benchmarks.ingest import bench_ingest
     from benchmarks.scenarios import bench_scenarios
@@ -1680,7 +1642,6 @@ SECTIONS = {
     "injection_overhead": bench_injection_overhead,
     "serving_phases": bench_serving_phases,
     "kernel_micro": bench_kernel_micro,
-    "roofline": bench_roofline,
     "feature_plane": bench_feature_plane,
     "serving": bench_serving,
     "serving_sharded": bench_serving_sharded,

@@ -54,7 +54,7 @@ import numpy as np
 
 # NOTE: jax is imported inside main(), after --mesh handling — forcing
 # host devices for the CPU multi-device path must precede the first jax
-# device query (same constraint as launch/dryrun.py).
+# device query.
 
 DAY = 86400
 

@@ -189,7 +189,9 @@ def _run_stack(params, x, *, cfg, mode, caches, positions, valid, q_chunk,
         rngs = {f"pos{p}": flat[:, p] for p in range(P)}
 
     xs = (params["blocks"], caches, rngs)
-    (x, aux_sum), caches_out = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    with jax.named_scope("layers"):
+        (x, aux_sum), caches_out = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), xs)
     return x, aux_sum, caches_out
 
 
@@ -224,6 +226,7 @@ def _embed(params, cfg, tokens, prefix_embeds, embed_mesh=None):
     return x
 
 
+@jax.named_scope("lm_head")
 def _logits(params, cfg: ModelConfig, x, head_sharding=None):
     table = (params["embed"]["table"] if cfg.tie_embeddings
              else params["lm_head"]["table"])

@@ -8,7 +8,7 @@ from repro.serving.api import (  # noqa: F401
     Event, GatewayStats, Request, RequestTelemetry, Response,
     RolloverStats, Ticket, as_event, assign_arms, hash_arm)
 from repro.serving.engine import (  # noqa: F401
-    ServingConfig, ServingEngine, make_serve_step)
+    ServingConfig, ServingEngine)
 from repro.serving.pool import (  # noqa: F401
     DeviceStatePool, PagedStateCache)
 from repro.serving.scheduler import (  # noqa: F401
@@ -24,7 +24,7 @@ __all__ = [
     "Event", "Request", "Response", "RequestTelemetry", "Ticket",
     "GatewayStats", "RolloverStats", "as_event", "hash_arm", "assign_arms",
     # engine (serving/engine.py)
-    "ServingConfig", "ServingEngine", "make_serve_step",
+    "ServingConfig", "ServingEngine",
     # paged device state pool (serving/pool.py)
     "DeviceStatePool", "PagedStateCache",
     # scheduler / facade (serving/scheduler.py)

@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.model import (cache_from_prefill, decode_step, extend,
                                 init_cache, prefill)
+from repro.serving.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,11 +66,11 @@ class ServingEngine:
         self.scfg = scfg
         self.mesh = mesh
         self._slate_fns: Dict[int, Any] = {}
-        pf = functools.partial(_prefill_impl, cfg=cfg, q_chunk=scfg.q_chunk)
-        inj = functools.partial(_inject_impl, cfg=cfg, q_chunk=scfg.q_chunk)
-        fin = functools.partial(_finalize_impl, cfg=cfg,
-                                capacity=scfg.cache_capacity)
-        dec = functools.partial(_decode_impl, cfg=cfg)
+        pf = _named_partial(_prefill_impl, cfg=cfg, q_chunk=scfg.q_chunk)
+        inj = _named_partial(_inject_impl, cfg=cfg, q_chunk=scfg.q_chunk)
+        fin = _named_partial(_finalize_impl, cfg=cfg,
+                             capacity=scfg.cache_capacity)
+        dec = _named_partial(_decode_impl, cfg=cfg)
         if mesh is None:
             self.data_shards = 1
             self.params = params
@@ -134,8 +135,8 @@ class ServingEngine:
         preallocation can never drift from what prefill actually
         produces."""
         b, p = self.scfg.max_batch, self.scfg.prefill_len
-        pf = functools.partial(_prefill_impl, cfg=self.cfg,
-                               q_chunk=self.scfg.q_chunk)
+        pf = _named_partial(_prefill_impl, cfg=self.cfg,
+                            q_chunk=self.scfg.q_chunk)
         pshapes = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
         return jax.eval_shape(pf, pshapes,
@@ -205,19 +206,20 @@ class ServingEngine:
             raise ValueError(
                 f"{len(seqs)} sequences exceed max_batch={b}; split the "
                 f"request wave into panes of at most {b} rows")
-        toks = np.zeros((b, length), np.int32)
-        valid = np.zeros((b, length), bool)
-        for i, s in enumerate(seqs):
-            s = list(s)[-length:]
-            if not s:
-                continue
-            if align == "right":
-                toks[i, length - len(s):] = s
-                valid[i, length - len(s):] = True
-            else:
-                toks[i, :len(s)] = s
-                valid[i, :len(s)] = True
-        return toks, valid
+        with span("repro.feature.tokens"):
+            toks = np.zeros((b, length), np.int32)
+            valid = np.zeros((b, length), bool)
+            for i, s in enumerate(seqs):
+                s = list(s)[-length:]
+                if not s:
+                    continue
+                if align == "right":
+                    toks[i, length - len(s):] = s
+                    valid[i, length - len(s):] = True
+                else:
+                    toks[i, :len(s)] = s
+                    valid[i, :len(s)] = True
+            return toks, valid
 
     # ------------------------------------------------------------------
     def _place(self, x, ns):
@@ -236,14 +238,15 @@ class ServingEngine:
         subsequent inject/decode positions continue at ``buf_len`` —
         relative distances between real tokens are exact under RoPE.
         """
-        tokens = self._place(jnp.asarray(tokens), self._tok_ns)
-        valid = self._place(jnp.asarray(valid), self._tok_ns)
-        logits, caches = self._prefill(self.params, tokens, valid)
-        b, s = tokens.shape
-        return {"caches": caches, "valid": valid,
-                # right-aligned prefill: every row's next position is S
-                "next_pos": jnp.full((b,), s, jnp.int32),
-                "logits": logits}
+        with span("repro.engine.prefill"):
+            tokens = self._place(jnp.asarray(tokens), self._tok_ns)
+            valid = self._place(jnp.asarray(valid), self._tok_ns)
+            logits, caches = self._prefill(self.params, tokens, valid)
+            b, s = tokens.shape
+            return {"caches": caches, "valid": valid,
+                    # right-aligned prefill: every row's next position is S
+                    "next_pos": jnp.full((b,), s, jnp.int32),
+                    "logits": logits}
 
     def inject(self, state: Dict[str, Any], suffix_tokens, suffix_valid,
                fallback_logits=None) -> Dict[str, Any]:
@@ -260,23 +263,27 @@ class ServingEngine:
         ``fallback_logits`` (B, Vp) is given — the pre-inject scores —
         the result also carries ``first_logits``: last-valid scores for
         rows with a real suffix, the fallback for empty rows."""
-        args = [self.params,
-                self._place(state["caches"], self._seq_ns),
-                self._place(jnp.asarray(suffix_tokens), self._tok_ns),
-                self._place(jnp.asarray(suffix_valid), self._tok_ns),
-                self._place(state["valid"], self._tok_ns),
-                self._place(state["next_pos"], self._row_ns)]
-        if fallback_logits is None:
-            return self._inject(*args)
-        return self._inject_fb(
-            *args, self._place(jnp.asarray(fallback_logits), self._tok_ns))
+        with span("repro.engine.inject"):
+            args = [self.params,
+                    self._place(state["caches"], self._seq_ns),
+                    self._place(jnp.asarray(suffix_tokens), self._tok_ns),
+                    self._place(jnp.asarray(suffix_valid), self._tok_ns),
+                    self._place(state["valid"], self._tok_ns),
+                    self._place(state["next_pos"], self._row_ns)]
+            if fallback_logits is None:
+                return self._inject(*args)
+            return self._inject_fb(
+                *args, self._place(jnp.asarray(fallback_logits),
+                                   self._tok_ns))
 
     def finalize(self, state: Dict[str, Any]) -> Dict[str, Any]:
         """Sequence-form state -> fixed-capacity ring cache for decode."""
-        caches = self._finalize(self._place(state["caches"], self._seq_ns),
-                                self._place(state["valid"], self._tok_ns))
-        return {"caches": caches,
-                "pos": self._place(state["next_pos"], self._row_ns)}
+        with span("repro.engine.finalize"):
+            caches = self._finalize(
+                self._place(state["caches"], self._seq_ns),
+                self._place(state["valid"], self._tok_ns))
+            return {"caches": caches,
+                    "pos": self._place(state["next_pos"], self._row_ns)}
 
     def decode(self, dec: Dict[str, Any], tokens) -> Tuple[jnp.ndarray, Dict]:
         """One serve step: tokens (B,1) -> (logits (B,Vp), updated dec)."""
@@ -315,30 +322,35 @@ class ServingEngine:
                 f"(temperature={self.scfg.temperature}) is not implemented "
                 "— drive decode()/sample() directly for sampled serving")
         dec = self.finalize(state)
-        key = slate_len if row_lens is None else ("masked", slate_len)
-        fn = self._slate_fns.get(key)
-        if fn is None:
-            body = _slate_impl if row_lens is None else _slate_masked_impl
-            impl = functools.partial(body, cfg=self.cfg,
-                                     slate_len=slate_len)
-            if self.mesh is None:
-                fn = jax.jit(impl)
-            elif row_lens is None:
-                fn = jax.jit(impl, in_shardings=(
-                    self._param_ns, self._ring_ns, self._row_ns,
-                    self._tok_ns), out_shardings=self._tok_ns)
+        with span("repro.engine.slate"):
+            key = slate_len if row_lens is None else ("masked", slate_len)
+            fn = self._slate_fns.get(key)
+            if fn is None:
+                body = _slate_impl if row_lens is None else _slate_masked_impl
+                impl = _named_partial(body, cfg=self.cfg,
+                                      slate_len=slate_len)
+                if self.mesh is None:
+                    fn = jax.jit(impl)
+                elif row_lens is None:
+                    fn = jax.jit(impl, in_shardings=(
+                        self._param_ns, self._ring_ns, self._row_ns,
+                        self._tok_ns), out_shardings=self._tok_ns)
+                else:
+                    fn = jax.jit(impl, in_shardings=(
+                        self._param_ns, self._ring_ns, self._row_ns,
+                        self._tok_ns, self._row_ns),
+                        out_shardings=self._tok_ns)
+                self._slate_fns[key] = fn
+            first = self._place(jnp.asarray(first_logits), self._tok_ns)
+            if row_lens is None:
+                slate = fn(self.params, dec["caches"], dec["pos"], first)
             else:
-                fn = jax.jit(impl, in_shardings=(
-                    self._param_ns, self._ring_ns, self._row_ns,
-                    self._tok_ns, self._row_ns), out_shardings=self._tok_ns)
-            self._slate_fns[key] = fn
-        first = self._place(jnp.asarray(first_logits), self._tok_ns)
-        if row_lens is None:
-            return np.asarray(fn(self.params, dec["caches"], dec["pos"],
-                                 first))
-        lens = self._place(jnp.asarray(row_lens, jnp.int32), self._row_ns)
-        return np.asarray(fn(self.params, dec["caches"], dec["pos"], first,
-                             lens))
+                lens = self._place(jnp.asarray(row_lens, jnp.int32),
+                                   self._row_ns)
+                slate = fn(self.params, dec["caches"], dec["pos"], first,
+                           lens)
+        with span("repro.engine.readback"):
+            return np.asarray(slate)
 
     def sample(self, logits, rng=None) -> jnp.ndarray:
         if self.scfg.temperature <= 0:
@@ -350,6 +362,13 @@ class ServingEngine:
 # ----------------------------------------------------------------------
 # jit bodies (pure functions of pytrees + static cfg)
 # ----------------------------------------------------------------------
+
+def _named_partial(fn, **static):
+    """``fn`` with its static arguments bound, keeping ``fn``'s name: XLA
+    names the compiled program ``jit_<name>`` (``jit__inject_impl``), where
+    a bare ``functools.partial`` comes out as ``jit__unknown``."""
+    return functools.update_wrapper(functools.partial(fn, **static), fn)
+
 
 def _prefill_impl(params, tokens, valid, *, cfg, q_chunk):
     return prefill(params, cfg, tokens, valid=valid, q_chunk=q_chunk)
@@ -368,10 +387,11 @@ def _inject_impl(params, caches, tokens, valid, prefix_valid, start,
     # GSPMD all-gather the whole (B,Ss,V) logits across the data axis.
     # HIGHEST keeps the selection an exact copy on the TPU, whose default
     # float32 matmul is one bfloat16 pass.
-    sel = (jnp.arange(logits.shape[1], dtype=jnp.int32)[None, :]
-           == jnp.maximum(n_valid - 1, 0)[:, None])
-    last_valid = jnp.einsum("bs,bsv->bv", sel.astype(logits.dtype), logits,
-                            precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope("select"):
+        sel = (jnp.arange(logits.shape[1], dtype=jnp.int32)[None, :]
+               == jnp.maximum(n_valid - 1, 0)[:, None])
+        last_valid = jnp.einsum("bs,bsv->bv", sel.astype(logits.dtype),
+                                logits, precision=jax.lax.Precision.HIGHEST)
     out = {
         "caches": caches, "logits": logits,
         "valid": jnp.concatenate([prefix_valid, valid], axis=1),
@@ -383,8 +403,9 @@ def _inject_impl(params, caches, tokens, valid, prefix_valid, start,
         # next-item scores per row: after the last real fresh event, or
         # the caller-supplied pre-inject scores when the row's suffix is
         # empty — computed here so the serve loop never syncs logits
-        out["first_logits"] = jnp.where(
-            (n_valid > 0)[:, None], last_valid, fallback_logits)
+        with jax.named_scope("select"):
+            out["first_logits"] = jnp.where(
+                (n_valid > 0)[:, None], last_valid, fallback_logits)
     return out
 
 
@@ -405,6 +426,7 @@ def _slate_impl(params, caches, pos, first, *, cfg, slate_len):
     """
     vocab_iota = jnp.arange(first.shape[-1], dtype=jnp.int32)
 
+    @jax.named_scope("pick")
     def pick(logits, mask):
         tok = jnp.argmax(jnp.where(mask, -1e30, logits),
                          axis=-1).astype(jnp.int32)
@@ -438,23 +460,7 @@ def _slate_masked_impl(params, caches, pos, first, row_lens, *, cfg,
     k-slate it would have been served alone."""
     slate = _slate_impl(params, caches, pos, first, cfg=cfg,
                         slate_len=slate_len)
-    keep = (jnp.arange(slate_len, dtype=jnp.int32)[None, :]
-            < row_lens[:, None])
-    return jnp.where(keep, slate, -1)
-
-
-# ----------------------------------------------------------------------
-# serve_step for the dry-run: ONE token against a seq_len cache
-# ----------------------------------------------------------------------
-
-def make_serve_step(cfg: ModelConfig):
-    """The function the decode-shape dry-runs lower: greedy one-token step.
-
-    signature: (params, caches, tokens (B,1), pos (B,)) ->
-               (next_token (B,), caches')
-    """
-    def serve_step(params, caches, tokens, pos):
-        logits, caches = decode_step(params, cfg, caches, tokens, pos)
-        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        return nxt, caches
-    return serve_step
+    with jax.named_scope("mask"):
+        keep = (jnp.arange(slate_len, dtype=jnp.int32)[None, :]
+                < row_lens[:, None])
+        return jnp.where(keep, slate, -1)

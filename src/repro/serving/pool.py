@@ -60,6 +60,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.serving.engine import ServingEngine
+from repro.serving.tracing import span
 
 
 # ----------------------------------------------------------------------
@@ -263,14 +264,15 @@ class DeviceStatePool:
         if len(slots) > b:
             raise ValueError(f"{len(slots)} rows exceed max_batch={b}")
         slots = list(slots) + [slots[0]] * (b - len(slots))
-        if self.engine.mesh is None:
-            state, last = self._gather(
-                self.caches, self.valid, self.next_pos, self.last_logits,
-                jnp.asarray(slots, jnp.int32))
-        else:
-            state, last = self._gather(self.caches, self.valid,
-                                       self.next_pos, self.last_logits,
-                                       self._onehot(slots))
+        with span("repro.pool.gather"):
+            if self.engine.mesh is None:
+                state, last = self._gather(
+                    self.caches, self.valid, self.next_pos,
+                    self.last_logits, jnp.asarray(slots, jnp.int32))
+            else:
+                state, last = self._gather(self.caches, self.valid,
+                                           self.next_pos, self.last_logits,
+                                           self._onehot(slots))
         self.gathers += 1
         return state, last
 
@@ -278,20 +280,21 @@ class DeviceStatePool:
         """Write prefill-pane rows into slots (row ``i`` -> ``slots[i]``;
         trailing pad rows of the pane are simply not listed). In-place:
         the pool buffers are donated into the update."""
-        oh = self._onehot(slots)
-        caches, valid = state["caches"], state["valid"]
-        next_pos, logits = state["next_pos"], state["logits"]
-        if self.engine.mesh is not None:
-            # replicate the pane over the data axes OUTSIDE the compiled
-            # program (see class docstring)
-            caches = jax.device_put(caches, self._cache_ns)
-            valid = jax.device_put(valid, self._valid_ns)
-            next_pos = jax.device_put(next_pos, self._rows_ns)
-            logits = jax.device_put(logits, self._st_logits_ns)
-        (self.caches, self.valid, self.next_pos,
-         self.last_logits) = self._scatter(
-            self.caches, self.valid, self.next_pos, self.last_logits,
-            caches, valid, next_pos, logits, oh)
+        with span("repro.pool.scatter"):
+            oh = self._onehot(slots)
+            caches, valid = state["caches"], state["valid"]
+            next_pos, logits = state["next_pos"], state["logits"]
+            if self.engine.mesh is not None:
+                # replicate the pane over the data axes OUTSIDE the
+                # compiled program (see class docstring)
+                caches = jax.device_put(caches, self._cache_ns)
+                valid = jax.device_put(valid, self._valid_ns)
+                next_pos = jax.device_put(next_pos, self._rows_ns)
+                logits = jax.device_put(logits, self._st_logits_ns)
+            (self.caches, self.valid, self.next_pos,
+             self.last_logits) = self._scatter(
+                self.caches, self.valid, self.next_pos, self.last_logits,
+                caches, valid, next_pos, logits, oh)
         self.scatters += 1
 
 

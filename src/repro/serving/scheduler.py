@@ -99,6 +99,7 @@ from repro.serving.api import (POLICIES, GatewayStats, Request,
                                RequestTelemetry, Response, RolloverStats,
                                Ticket, as_event)
 from repro.serving.engine import ServingEngine
+from repro.serving.tracing import span
 
 
 # ----------------------------------------------------------------------
@@ -840,15 +841,16 @@ class Gateway:
         — the same hook signature the platform exposes. The user id is
         validated against *both* stores up front so a rejected event
         mutates neither."""
-        ev = as_event(ev)
-        limit = self._event_user_limit()
-        if not 0 <= ev.user < limit:
-            raise IndexError(
-                f"event user {ev.user} out of range [0, {limit}) for the "
-                f"feature stores; nothing was ingested")
-        self.injector.batch.append(ev.user, ev.item, ev.ts)
-        if self.injector.realtime is not None:
-            self.injector.realtime.ingest(ev.user, ev.item, ev.ts)
+        with span("repro.feature.observe"):
+            ev = as_event(ev)
+            limit = self._event_user_limit()
+            if not 0 <= ev.user < limit:
+                raise IndexError(
+                    f"event user {ev.user} out of range [0, {limit}) for the "
+                    f"feature stores; nothing was ingested")
+            self.injector.batch.append(ev.user, ev.item, ev.ts)
+            if self.injector.realtime is not None:
+                self.injector.realtime.ingest(ev.user, ev.item, ev.ts)
 
     def observe_many(self, users, items, tss) -> None:
         """Columnar bulk ingest (parallel arrays) of feedback events.
@@ -859,23 +861,24 @@ class Gateway:
         the log had already extended — a bad batch left the two stores
         silently diverged (events the merge would count once instead of
         twice, or the reverse). A rejected batch now mutates nothing."""
-        users = np.asarray(users, np.int64).ravel()
-        items = np.asarray(items).ravel()
-        tss = np.asarray(tss).ravel()
-        if not (len(users) == len(items) == len(tss)):
-            raise ValueError(
-                f"observe_many wants parallel arrays; got lengths "
-                f"users={len(users)} items={len(items)} ts={len(tss)}")
-        if len(users):
-            limit = self._event_user_limit()
-            lo, hi = int(users.min()), int(users.max())
-            if lo < 0 or hi >= limit:
-                raise IndexError(
-                    f"event user ids out of range [0, {limit}): "
-                    f"[{lo}, {hi}]; nothing was ingested")
-        self.injector.batch.extend(users, items, tss)
-        if self.injector.realtime is not None:
-            self.injector.realtime.extend(users, items, tss)
+        with span("repro.feature.observe"):
+            users = np.asarray(users, np.int64).ravel()
+            items = np.asarray(items).ravel()
+            tss = np.asarray(tss).ravel()
+            if not (len(users) == len(items) == len(tss)):
+                raise ValueError(
+                    f"observe_many wants parallel arrays; got lengths "
+                    f"users={len(users)} items={len(items)} ts={len(tss)}")
+            if len(users):
+                limit = self._event_user_limit()
+                lo, hi = int(users.min()), int(users.max())
+                if lo < 0 or hi >= limit:
+                    raise IndexError(
+                        f"event user ids out of range [0, {limit}): "
+                        f"[{lo}, {hi}]; nothing was ingested")
+            self.injector.batch.extend(users, items, tss)
+            if self.injector.realtime is not None:
+                self.injector.realtime.extend(users, items, tss)
 
     def tick(self, now: int) -> List[Ticket]:
         """Advance the gateway clock: advance/roll due snapshots (warm
@@ -953,16 +956,17 @@ class Gateway:
         ``shed_policy="deadline"`` an arrival whose projected completion
         already exceeds its deadline is rejected here — its ticket
         resolves immediately with the shed marker and never enqueues."""
-        self._check_request(request)
-        self._advance(request.now)
-        t = Ticket(request, self._next_id, time.perf_counter())
-        self._next_id += 1
-        if self._should_shed(request, len(self._queue)):
-            self._shed_ticket(t)
+        with span("repro.gateway.submit"):
+            self._check_request(request)
+            self._advance(request.now)
+            t = Ticket(request, self._next_id, time.perf_counter())
+            self._next_id += 1
+            if self._should_shed(request, len(self._queue)):
+                self._shed_ticket(t)
+                return t
+            self._queue.append(t)
+            self._maybe_flush()
             return t
-        self._queue.append(t)
-        self._maybe_flush()
-        return t
 
     def submit_many(self, requests: Sequence[Request]) -> List[Ticket]:
         """Enqueue a batch of arrivals that are known together (a wave).
@@ -972,28 +976,30 @@ class Gateway:
         sees all of it at once — this is exactly the legacy wave
         semantics, and full panes are flushed eagerly; a short remainder
         stays queued for deadline/flush."""
-        for req in requests:
-            # validate the WHOLE batch before enqueuing any of it: a bad
-            # request mid-batch must not leave earlier rows queued with
-            # their ticket handles lost to the exception
-            self._check_request(req)
-        tickets = []
-        for req in requests:
-            t = Ticket(req, self._next_id, time.perf_counter())
-            self._next_id += 1
-            self._advance(req.now)
-            if self._should_shed(req, len(self._queue)):
-                self._shed_ticket(t)
-            else:
-                self._queue.append(t)
-            tickets.append(t)
-        self._maybe_flush()
-        return tickets
+        with span("repro.gateway.submit"):
+            for req in requests:
+                # validate the WHOLE batch before enqueuing any of it: a bad
+                # request mid-batch must not leave earlier rows queued with
+                # their ticket handles lost to the exception
+                self._check_request(req)
+            tickets = []
+            for req in requests:
+                t = Ticket(req, self._next_id, time.perf_counter())
+                self._next_id += 1
+                self._advance(req.now)
+                if self._should_shed(req, len(self._queue)):
+                    self._shed_ticket(t)
+                else:
+                    self._queue.append(t)
+                tickets.append(t)
+            self._maybe_flush()
+            return tickets
 
     def flush(self, now: Optional[int] = None) -> List[Ticket]:
         """Serve everything queued (the last pane padded if short)."""
-        self._advance(now)
-        return self._drain(full_panes_only=False)
+        with span("repro.gateway.submit"):
+            self._advance(now)
+            return self._drain(full_panes_only=False)
 
     def poll(self) -> List[Ticket]:
         """Claim every ticket whose row has retired since the last
@@ -1195,21 +1201,22 @@ class Gateway:
         one store lookup per history flavor ("batch"/"inject" share the
         snapshot prefix; "fresh" reads at the serve cutoff) instead of
         one per distinct arrival time."""
-        out: List[Optional[List[int]]] = [None] * len(reqs)
-        groups: "OrderedDict[bool, List[int]]" = OrderedDict()
-        for i, pol in enumerate(policies):
-            groups.setdefault(pol == "fresh", []).append(i)
-        for fresh, rows in groups.items():
-            users = np.asarray([reqs[i].user for i in rows], np.int64)
-            if fresh:
-                items, _, valid = self.injector.batch.lookup_at_cutoff(
-                    users, now)
-            else:
-                items, _, valid = self.injector.batch.lookup(users, now)
-            toks = items_to_tokens(items, valid)
-            for j, i in enumerate(rows):
-                out[i] = toks[j][valid[j] > 0].tolist()
-        return out  # type: ignore[return-value]
+        with span("repro.feature.histories"):
+            out: List[Optional[List[int]]] = [None] * len(reqs)
+            groups: "OrderedDict[bool, List[int]]" = OrderedDict()
+            for i, pol in enumerate(policies):
+                groups.setdefault(pol == "fresh", []).append(i)
+            for fresh, rows in groups.items():
+                users = np.asarray([reqs[i].user for i in rows], np.int64)
+                if fresh:
+                    items, _, valid = self.injector.batch.lookup_at_cutoff(
+                        users, now)
+                else:
+                    items, _, valid = self.injector.batch.lookup(users, now)
+                toks = items_to_tokens(items, valid)
+                for j, i in enumerate(rows):
+                    out[i] = toks[j][valid[j] > 0].tolist()
+            return out  # type: ignore[return-value]
 
     def _suffixes(self, reqs: Sequence[Request], policies: Sequence[str],
                   now: int) -> List[List[int]]:
@@ -1217,85 +1224,95 @@ class Gateway:
         "inject" rows carry one (a single ``fresh_suffix_tokens`` call
         per pane, capped at inject_len newest events — see its docstring
         for why truncation happens before tokenization)."""
-        out: List[List[int]] = [[] for _ in reqs]
-        if self.injector.realtime is None:
+        with span("repro.feature.suffixes"):
+            out: List[List[int]] = [[] for _ in reqs]
+            if self.injector.realtime is None:
+                return out
+            rows = [i for i, pol in enumerate(policies) if pol == "inject"]
+            if not rows:
+                return out
+            users = np.asarray([reqs[i].user for i in rows], np.int64)
+            sfx = self.injector.fresh_suffix_tokens(
+                users, now, cap=self.engine.scfg.inject_len)
+            for j, i in enumerate(rows):
+                out[i] = sfx[j]
             return out
-        rows = [i for i, pol in enumerate(policies) if pol == "inject"]
-        if not rows:
-            return out
-        users = np.asarray([reqs[i].user for i in rows], np.int64)
-        sfx = self.injector.fresh_suffix_tokens(
-            users, now, cap=self.engine.scfg.inject_len)
-        for j, i in enumerate(rows):
-            out[i] = sfx[j]
-        return out
 
     # ------------------------------------------------------------------
     # Pane execution
     # ------------------------------------------------------------------
 
     def _execute(self, pane: List[Ticket], gen: Tuple[int, int]) -> None:
-        eng = self.engine
         pane_id = self.panes
         self.panes += 1
-        reqs = [t.request for t in pane]
-        now = int(self._clock)  # serve-time feature clock for the pane
-        policies = [self._policy_of(r) for r in reqs]
-        slate_lens = [r.slate_len or self.cfg.slate_len for r in reqs]
-        # per-pane-row results, scattered by the policy branches below
-        row_slate: List[Optional[np.ndarray]] = [None] * len(reqs)
-        row_scores: List[Optional[np.ndarray]] = [None] * len(reqs)
-        hit_all = [False] * len(reqs)
-        path_all = [""] * len(reqs)
+        with span("repro.gateway.pane", pane=pane_id, rows=len(pane)):
+            reqs = [t.request for t in pane]
+            now = int(self._clock)  # serve-time feature clock for the pane
+            policies = [self._policy_of(r) for r in reqs]
+            slate_lens = [r.slate_len or self.cfg.slate_len for r in reqs]
+            # per-pane-row results, scattered by the policy branches below
+            row_slate: List[Optional[np.ndarray]] = [None] * len(reqs)
+            row_scores: List[Optional[np.ndarray]] = [None] * len(reqs)
+            hit_all = [False] * len(reqs)
+            path_all = [""] * len(reqs)
 
-        # "decay" rows are served model-free (no engine state, no cache
-        # entry): slates ranked by exponentially time-decayed event
-        # scores over the row's cutoff-exact features. Carved out here
-        # so the engine pane below only carries model-scored rows —
-        # rows are independent, so the split cannot change any result.
-        drows = [i for i, p in enumerate(policies) if p == "decay"]
-        if drows:
-            self._serve_decay(reqs, drows, slate_lens, now,
-                              row_slate, row_scores, path_all)
-        erows = [i for i, p in enumerate(policies) if p != "decay"]
-        if erows:
-            self._serve_engine(reqs, erows, policies, slate_lens, gen, now,
-                               row_slate, row_scores, hit_all, path_all)
+            # "decay" rows are served model-free (no engine state, no
+            # cache entry): slates ranked by exponentially time-decayed
+            # event scores over the row's cutoff-exact features. Carved out
+            # here so the engine pane below only carries model-scored rows
+            # — rows are independent, so the split cannot change any
+            # result.
+            drows = [i for i, p in enumerate(policies) if p == "decay"]
+            if drows:
+                self._serve_decay(reqs, drows, slate_lens, now,
+                                  row_slate, row_scores, path_all)
+            erows = [i for i, p in enumerate(policies) if p != "decay"]
+            if erows:
+                self._serve_engine(reqs, erows, policies, slate_lens, gen,
+                                   now, row_slate, row_scores, hit_all,
+                                   path_all)
 
-        # service model: with pane_service_time set, this pane occupies
-        # the server for `cost` sim-seconds past whenever it frees up —
-        # completion times (and therefore queue delays and deadline
-        # misses) account for the backlog, not just the flush clock
-        cost = self.cfg.pane_service_time
-        if cost is None:
-            done_at = int(self._clock)
-        else:
-            self._busy_until = max(self._busy_until, int(self._clock)) + cost
-            done_at = self._busy_until
-        wall = time.perf_counter()
-        for i, (t, pol) in enumerate(zip(pane, policies)):
-            tel = RequestTelemetry(
-                request_id=t.request_id, user=t.request.user, policy=pol,
-                slate_len=slate_lens[i], pane_id=pane_id,
-                # clamped at 0: the deprecated legacy shim rewinds the
-                # otherwise-monotonic clock for non-monotonic serve(now)
-                # replays, and a pending request from a later wave would
-                # otherwise record a negative delay and pollute the
-                # stats() queue-delay percentiles
-                queue_delay=max(0, int(done_at - t.request.now)),
-                cache_hit=hit_all[i], path=path_all[i], generation=gen[0],
-                submitted_at=t.request.now, served_at=done_at,
-                tag=t.request.tag, model_version=gen[1])
-            t.response = Response(slate=row_slate[i], scores=row_scores[i],
-                                  telemetry=tel)
-            t.completed_wall = wall
-            if t.request.deadline is not None \
-                    and done_at > t.request.deadline:
-                self.deadline_misses += 1
-            self._path_counts[path_all[i]] += 1
-            self._queue_delays.append(tel.queue_delay)
-        self._completed.extend(pane)  # rows retire -> claimable via poll()
-        self.requests += len(pane)
+            with span("repro.gateway.respond"):
+                # service model: with pane_service_time set, this pane
+                # occupies the server for `cost` sim-seconds past whenever
+                # it frees up — completion times (and therefore queue
+                # delays and deadline misses) account for the backlog,
+                # not just the flush clock
+                cost = self.cfg.pane_service_time
+                if cost is None:
+                    done_at = int(self._clock)
+                else:
+                    self._busy_until = max(self._busy_until,
+                                           int(self._clock)) + cost
+                    done_at = self._busy_until
+                wall = time.perf_counter()
+                for i, (t, pol) in enumerate(zip(pane, policies)):
+                    tel = RequestTelemetry(
+                        request_id=t.request_id, user=t.request.user,
+                        policy=pol, slate_len=slate_lens[i], pane_id=pane_id,
+                        # clamped at 0: the deprecated legacy shim rewinds
+                        # the otherwise-monotonic clock for non-monotonic
+                        # serve(now) replays, and a pending request from a
+                        # later wave would otherwise record a negative
+                        # delay and pollute the stats() queue-delay
+                        # percentiles
+                        queue_delay=max(0, int(done_at - t.request.now)),
+                        cache_hit=hit_all[i], path=path_all[i],
+                        generation=gen[0], submitted_at=t.request.now,
+                        served_at=done_at, tag=t.request.tag,
+                        model_version=gen[1])
+                    t.response = Response(slate=row_slate[i],
+                                          scores=row_scores[i],
+                                          telemetry=tel)
+                    t.completed_wall = wall
+                    if t.request.deadline is not None \
+                            and done_at > t.request.deadline:
+                        self.deadline_misses += 1
+                    self._path_counts[path_all[i]] += 1
+                    self._queue_delays.append(tel.queue_delay)
+                # rows retire -> claimable via poll()
+                self._completed.extend(pane)
+                self.requests += len(pane)
 
     def _serve_decay(self, reqs: Sequence[Request], rows: Sequence[int],
                      slate_lens: Sequence[int], now: int,
@@ -1402,7 +1419,8 @@ class Gateway:
                      for h, s in zip(hit_flags, suffix)]
 
         slate, _ = self._decode(state, first, elens)
-        scores = np.asarray(first, np.float32)
+        with span("repro.gateway.readback"):
+            scores = np.asarray(first, np.float32)
         for j, i in enumerate(rows):
             row_slate[i] = slate[j, :elens[j]].copy()
             row_scores[i] = scores[j].copy()

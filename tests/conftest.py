@@ -23,8 +23,8 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Tests run against the single real CPU device — the forced-host-device
-# trick (launch/dryrun.py; benchmarks serving_sharded; the subprocess
-# spawned by tests/test_serving_sharded.py) must never leak into this
+# trick (benchmarks serving_sharded; the subprocess spawned by
+# tests/test_serving_sharded.py) must never leak into this
 # process: jax locks the device count at first init, so a leaked flag
 # would silently change every test's device topology.
 assert "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""), \

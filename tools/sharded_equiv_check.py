@@ -1,8 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=8")
-# ^ MUST precede any jax import — the dry-run trick (launch/dryrun.py):
-# jax locks the device count on first init. This script is run as a
+# ^ MUST precede any jax import: jax locks the device count on first
+# init. This script is run as a
 # SUBPROCESS by tests/test_serving_sharded.py precisely so the forced
 # device count never leaks into the main test process (conftest.py
 # asserts it doesn't).
