@@ -233,6 +233,9 @@ class GatewayStats:
     """
     requests: int
     panes: int
+    # panes launched while an earlier pane of the same drain was still
+    # unread (the drain keeps one pane in flight)
+    panes_overlapped: int
     pending: int              # queued, not yet served
     completed: int            # served, not yet claimed by poll()/drain()
     prefill_calls: int

@@ -295,7 +295,8 @@ class ServingEngine:
         return logits[:, 0], {"caches": caches, "pos": dec["pos"] + 1}
 
     def decode_slate(self, state: Dict[str, Any], first_logits,
-                     slate_len: int, row_lens=None) -> np.ndarray:
+                     slate_len: int, row_lens=None, wait: bool = True,
+                     ) -> np.ndarray | PendingSlate:
         """finalize + a greedy distinct-item slate in ONE jit call.
 
         The per-token python loop (mask → argmax → decode → sync) used to
@@ -315,6 +316,11 @@ class ServingEngine:
         (greedy decode is a prefix-stable sequence), so callers just
         slice. ``row_lens`` is a traced operand — one compiled program
         serves every mix of lengths at a given pane max.
+
+        ``wait=False`` returns as soon as the slate is launched, as a
+        :class:`PendingSlate` that ``np.asarray`` reads back: the caller
+        can launch the next pane's programs first, so the device has them
+        queued when this slate ends.
         """
         if self.scfg.temperature > 0:
             raise NotImplementedError(
@@ -349,14 +355,34 @@ class ServingEngine:
                                    self._row_ns)
                 slate = fn(self.params, dec["caches"], dec["pos"], first,
                            lens)
-        with span("repro.engine.readback"):
-            return np.asarray(slate)
+        pending = PendingSlate(slate)
+        return np.asarray(pending) if wait else pending
 
     def sample(self, logits, rng=None) -> jnp.ndarray:
         if self.scfg.temperature <= 0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return jax.random.categorical(
             rng, logits / self.scfg.temperature, axis=-1).astype(jnp.int32)
+
+
+class PendingSlate:
+    """A slate launched by ``decode_slate(wait=False)`` and not read back.
+
+    ``np.asarray(p)`` waits for the device and reads the int32 (B,
+    slate_len) slate back under the ``repro.engine.readback`` span;
+    ``p.copy()`` does the same into a writable array. Reading twice
+    gives the same values."""
+
+    def __init__(self, slate: jax.Array):
+        self._slate = slate
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        with span("repro.engine.readback"):
+            out = np.asarray(self._slate, dtype)
+        return out.copy() if copy else out
+
+    def copy(self) -> np.ndarray:
+        return np.asarray(self).copy()
 
 
 # ----------------------------------------------------------------------

@@ -98,7 +98,7 @@ from repro.core.pipeline import items_to_tokens
 from repro.serving.api import (POLICIES, GatewayStats, Request,
                                RequestTelemetry, Response, RolloverStats,
                                Ticket, as_event)
-from repro.serving.engine import ServingEngine
+from repro.serving.engine import PendingSlate, ServingEngine
 from repro.serving.tracing import span
 
 
@@ -501,6 +501,26 @@ class ServerConfig:
 # The Gateway
 # ----------------------------------------------------------------------
 
+@dataclasses.dataclass
+class _Launched:
+    """One pane between ``Gateway._launch`` and ``Gateway._retire``: what
+    the launch decided per row, with the engine rows' (``erows``) pending
+    slate and next-item scores still on the device."""
+    tickets: List[Ticket]
+    pane_id: int
+    gen: Tuple[int, int]
+    now: int
+    policies: List[str]
+    slate_lens: List[int]
+    row_slate: List[Optional[np.ndarray]]
+    row_scores: List[Optional[np.ndarray]]
+    hit_all: List[bool]
+    path_all: List[str]
+    erows: List[int]
+    slate: Optional[PendingSlate] = None
+    first: Any = None
+
+
 class Gateway:
     """The unified serving facade: request submission, micro-batching,
     event ingestion and clock/snapshot management in one object.
@@ -562,6 +582,7 @@ class Gateway:
         # counters / telemetry
         self.requests = 0
         self.panes = 0
+        self.panes_overlapped = 0  # launched while an earlier pane was unread
         self.prefill_calls = 0
         self.inject_calls = 0
         self.decode_steps = 0
@@ -1171,21 +1192,35 @@ class Gateway:
             order = np.argsort(is_miss, kind="stable")  # hits first
             q = [q[i] for i in order]
         # adopt the (possibly reordered) queue up front and dequeue pane
-        # by pane AS each one serves: if a later pane raises, the served
+        # by pane AS each one retires: if a later pane raises, the served
         # tickets are already out of the queue — a retried flush must
         # never re-execute a pane whose responses the caller may hold
         self._queue = q
         served: List[Ticket] = shed
-        while len(self._queue) >= b:
-            pane = self._queue[:b]
-            self._execute(pane, gen)
-            self._queue = self._queue[b:]
-            served.extend(pane)
-        if not full_panes_only and self._queue:
-            pane = list(self._queue)
-            self._execute(pane, gen)
-            self._queue = []
-            served.extend(pane)
+        panes = [q[i:i + b] for i in range(0, len(q), b)]
+        if full_panes_only and len(panes[-1]) < b:
+            panes.pop()
+
+        def retire(p: _Launched) -> None:
+            self._retire(p)
+            self._queue = self._queue[len(p.tickets):]
+            served.extend(p.tickets)
+
+        # one pane in flight: pane k+1 is launched before pane k is read
+        # back, so the device has k+1's programs queued when k's slate
+        # ends and the host's work between panes runs under it
+        inflight: Optional[_Launched] = None
+        for pane in panes:
+            try:
+                launched = self._launch(pane, gen,
+                                        overlapped=inflight is not None)
+            finally:
+                # pane k retires even when pane k+1's launch raises
+                if inflight is not None:
+                    retire(inflight)
+            inflight = launched
+        if inflight is not None:
+            retire(inflight)
         return served
 
     # ------------------------------------------------------------------
@@ -1242,19 +1277,29 @@ class Gateway:
     # Pane execution
     # ------------------------------------------------------------------
 
-    def _execute(self, pane: List[Ticket], gen: Tuple[int, int]) -> None:
+    def _launch(self, pane: List[Ticket], gen: Tuple[int, int],
+                overlapped: bool) -> _Launched:
+        """A pane's first half: features, state assembly and every device
+        launch up to the slate, which is left running. Nothing here reads
+        a device value back (the host LRU's admission aside: it copies its
+        prefill to the host). ``overlapped``: an earlier pane of the same
+        drain is still unread."""
         pane_id = self.panes
         self.panes += 1
-        with span("repro.gateway.pane", pane=pane_id, rows=len(pane)):
+        self.panes_overlapped += int(overlapped)
+        with span("repro.gateway.pane", pane=pane_id, rows=len(pane),
+                  overlapped=overlapped):
             reqs = [t.request for t in pane]
-            now = int(self._clock)  # serve-time feature clock for the pane
+            n = len(reqs)
             policies = [self._policy_of(r) for r in reqs]
-            slate_lens = [r.slate_len or self.cfg.slate_len for r in reqs]
-            # per-pane-row results, scattered by the policy branches below
-            row_slate: List[Optional[np.ndarray]] = [None] * len(reqs)
-            row_scores: List[Optional[np.ndarray]] = [None] * len(reqs)
-            hit_all = [False] * len(reqs)
-            path_all = [""] * len(reqs)
+            p = _Launched(
+                tickets=pane, pane_id=pane_id, gen=gen,
+                now=int(self._clock),  # serve-time feature clock
+                policies=policies,
+                slate_lens=[r.slate_len or self.cfg.slate_len for r in reqs],
+                row_slate=[None] * n, row_scores=[None] * n,
+                hit_all=[False] * n, path_all=[""] * n,
+                erows=[i for i, pol in enumerate(policies) if pol != "decay"])
 
             # "decay" rows are served model-free (no engine state, no
             # cache entry): slates ranked by exponentially time-decayed
@@ -1262,15 +1307,27 @@ class Gateway:
             # here so the engine pane below only carries model-scored rows
             # — rows are independent, so the split cannot change any
             # result.
-            drows = [i for i, p in enumerate(policies) if p == "decay"]
+            drows = [i for i, pol in enumerate(policies) if pol == "decay"]
             if drows:
-                self._serve_decay(reqs, drows, slate_lens, now,
-                                  row_slate, row_scores, path_all)
-            erows = [i for i, p in enumerate(policies) if p != "decay"]
-            if erows:
-                self._serve_engine(reqs, erows, policies, slate_lens, gen,
-                                   now, row_slate, row_scores, hit_all,
-                                   path_all)
+                self._serve_decay(reqs, drows, p.slate_lens, p.now,
+                                  p.row_slate, p.row_scores, p.path_all)
+            if p.erows:
+                p.slate, p.first = self._serve_engine(
+                    reqs, p.erows, policies, p.slate_lens, gen, p.now,
+                    p.hit_all, p.path_all)
+            return p
+
+    def _retire(self, p: _Launched) -> None:
+        """A pane's second half: read its slate and scores back, then
+        resolve each row's ticket."""
+        with span("repro.gateway.retire", pane=p.pane_id):
+            if p.erows:
+                slate = np.asarray(p.slate)
+                with span("repro.gateway.readback"):
+                    scores = np.asarray(p.first, np.float32)
+                for j, i in enumerate(p.erows):
+                    p.row_slate[i] = slate[j, :p.slate_lens[i]].copy()
+                    p.row_scores[i] = scores[j].copy()
 
             with span("repro.gateway.respond"):
                 # service model: with pane_service_time set, this pane
@@ -1280,16 +1337,16 @@ class Gateway:
                 # not just the flush clock
                 cost = self.cfg.pane_service_time
                 if cost is None:
-                    done_at = int(self._clock)
+                    done_at = p.now
                 else:
-                    self._busy_until = max(self._busy_until,
-                                           int(self._clock)) + cost
+                    self._busy_until = max(self._busy_until, p.now) + cost
                     done_at = self._busy_until
                 wall = time.perf_counter()
-                for i, (t, pol) in enumerate(zip(pane, policies)):
+                for i, (t, pol) in enumerate(zip(p.tickets, p.policies)):
                     tel = RequestTelemetry(
                         request_id=t.request_id, user=t.request.user,
-                        policy=pol, slate_len=slate_lens[i], pane_id=pane_id,
+                        policy=pol, slate_len=p.slate_lens[i],
+                        pane_id=p.pane_id,
                         # clamped at 0: the deprecated legacy shim rewinds
                         # the otherwise-monotonic clock for non-monotonic
                         # serve(now) replays, and a pending request from a
@@ -1297,22 +1354,22 @@ class Gateway:
                         # delay and pollute the stats() queue-delay
                         # percentiles
                         queue_delay=max(0, int(done_at - t.request.now)),
-                        cache_hit=hit_all[i], path=path_all[i],
-                        generation=gen[0], submitted_at=t.request.now,
+                        cache_hit=p.hit_all[i], path=p.path_all[i],
+                        generation=p.gen[0], submitted_at=t.request.now,
                         served_at=done_at, tag=t.request.tag,
-                        model_version=gen[1])
-                    t.response = Response(slate=row_slate[i],
-                                          scores=row_scores[i],
+                        model_version=p.gen[1])
+                    t.response = Response(slate=p.row_slate[i],
+                                          scores=p.row_scores[i],
                                           telemetry=tel)
                     t.completed_wall = wall
                     if t.request.deadline is not None \
                             and done_at > t.request.deadline:
                         self.deadline_misses += 1
-                    self._path_counts[path_all[i]] += 1
+                    self._path_counts[p.path_all[i]] += 1
                     self._queue_delays.append(tel.queue_delay)
                 # rows retire -> claimable via poll()
-                self._completed.extend(pane)
-                self.requests += len(pane)
+                self._completed.extend(p.tickets)
+                self.requests += len(p.tickets)
 
     def _serve_decay(self, reqs: Sequence[Request], rows: Sequence[int],
                      slate_lens: Sequence[int], now: int,
@@ -1337,9 +1394,11 @@ class Gateway:
     def _serve_engine(self, reqs: Sequence[Request], rows: Sequence[int],
                       policies: Sequence[str], slate_lens: Sequence[int],
                       gen: Tuple[int, int], now: int,
-                      row_slate: List, row_scores: List,
-                      hit_all: List[bool], path_all: List[str]) -> None:
-        """The model-scored pane body (every non-"decay" row)."""
+                      hit_all: List[bool], path_all: List[str],
+                      ) -> Tuple[PendingSlate, Any]:
+        """The model-scored pane body (every non-"decay" row), launched:
+        returns the engine's pending slate and the rows' next-item scores
+        on the device, in ``rows`` order."""
         eng = self.engine
         ereqs = [reqs[i] for i in rows]
         epol = [policies[i] for i in rows]
@@ -1418,18 +1477,15 @@ class Gateway:
             paths = ["prefill" if not h else ("inject" if s else "cached")
                      for h, s in zip(hit_flags, suffix)]
 
-        slate, _ = self._decode(state, first, elens)
-        with span("repro.gateway.readback"):
-            scores = np.asarray(first, np.float32)
         for j, i in enumerate(rows):
-            row_slate[i] = slate[j, :elens[j]].copy()
-            row_scores[i] = scores[j].copy()
             hit_all[i] = hit_flags[j]
             path_all[i] = paths[j]
+        return self._decode(state, first, elens), first
 
     def _decode(self, state: Dict[str, Any], first_logits,
-                slate_lens: Sequence[int]) -> Tuple[np.ndarray, int]:
-        """finalize -> greedy slate, one jit call for the whole pane.
+                slate_lens: Sequence[int]) -> PendingSlate:
+        """finalize -> greedy slate, one jit call for the whole pane,
+        launched and not waited for.
 
         Uniform panes (every row on the configured default) take the
         exact decode program the wave path always ran; heterogeneous
@@ -1437,16 +1493,14 @@ class Gateway:
         -1 inside the jit (see ServingEngine.decode_slate)."""
         eng = self.engine
         max_len = max(slate_lens)
-        if all(sl == slate_lens[0] for sl in slate_lens):
-            slate = eng.decode_slate(state, first_logits, max_len)
-        else:
-            b = eng.scfg.max_batch
-            row_lens = np.full(b, max_len, np.int32)
+        row_lens = None
+        if any(sl != slate_lens[0] for sl in slate_lens):
+            row_lens = np.full(eng.scfg.max_batch, max_len, np.int32)
             row_lens[:len(slate_lens)] = slate_lens
-            slate = eng.decode_slate(state, first_logits, max_len,
-                                     row_lens=row_lens)
+        slate = eng.decode_slate(state, first_logits, max_len,
+                                 row_lens=row_lens, wait=False)
         self.decode_steps += max_len - 1
-        return slate, max_len
+        return slate
 
     def _lookup_or_admit(self, reqs: Sequence[Request],
                          policies: Sequence[str],
@@ -1748,6 +1802,7 @@ class Gateway:
         delays = np.asarray(self._queue_delays, np.int64)
         return GatewayStats(
             requests=self.requests, panes=self.panes,
+            panes_overlapped=self.panes_overlapped,
             pending=len(self._queue),
             completed=len(self._completed),
             prefill_calls=self.prefill_calls,

@@ -10,8 +10,12 @@ a gap on the device reads as the host work around it. Spans of one
 thread nest, so a span's self time is its length less its children's.
 
     repro.gateway.submit     submit / submit_many / flush, pane formation
-    repro.gateway.pane       one pane (meta: pane id, rows); requests link
-                             to it by RequestTelemetry.pane_id
+    repro.gateway.pane       one pane launched (meta: pane id, rows,
+                             overlapped); requests link to it by
+                             RequestTelemetry.pane_id
+    repro.gateway.retire     one pane read back and answered (meta: pane
+                             id); in a drain of several panes it follows
+                             the next pane's launch
     repro.gateway.readback   the pane's scores read back to the host
     repro.gateway.respond    telemetry and Response of each row
     repro.feature.observe    observe / observe_many

@@ -106,33 +106,94 @@ def test_submit_rejects_out_of_range_user():
     assert gw.pending == 0
 
 
-def test_drain_dequeues_each_pane_as_it_serves(monkeypatch):
+@pytest.mark.parametrize("half", ["_launch", "_retire"])
+def test_drain_dequeues_each_pane_as_it_serves(monkeypatch, half):
     """If a later pane raises mid-drain, already-served tickets must be
     out of the queue: a retried flush may re-try the failed pane but
-    must never re-execute responses the caller already holds."""
+    must never re-execute responses the caller already holds. Pane 2
+    fails in its launch (pane 1, then in flight, retires and dequeues
+    before the exception leaves) or in its retire."""
     gw = _gateway()
     now = 5 * DAY + 100
-    real_execute = type(gw)._execute
+    real = getattr(type(gw), half)
     calls = {"n": 0}
 
-    def flaky(self, pane, gen):
+    def flaky(self, *a, **k):
         calls["n"] += 1
         if calls["n"] == 2:
             raise RuntimeError("injected pane failure")
-        real_execute(self, pane, gen)
+        return real(self, *a, **k)
 
-    monkeypatch.setattr(type(gw), "_execute", flaky)
+    monkeypatch.setattr(type(gw), half, flaky)
     reqs = [Request(user=u, now=now) for u in range(8)]  # 2 panes at b=4
     with pytest.raises(RuntimeError, match="injected"):
         gw.submit_many(reqs)
     # pane 1 served and dequeued; pane 2 failed and stayed queued
     assert gw.pending == 4 and gw.requests == 4
-    monkeypatch.setattr(type(gw), "_execute", real_execute)
+    monkeypatch.setattr(type(gw), half, real)
     first_pane_ids = [t.response.telemetry.pane_id
                       for t in gw.flush(now) if t.response]
     # recovery serves ONLY the failed pane; earlier responses untouched
     assert gw.requests == 8 and gw.pending == 0
     assert len(first_pane_ids) == 4
+
+
+def test_a_failed_retire_keeps_its_pane_and_the_one_in_flight_queued(
+        monkeypatch):
+    """Pane 1's retire raises after pane 2 was launched: neither pane's
+    responses reached the caller, so both stay queued, and the retried
+    flush serves them bitwise as a gateway that never failed."""
+    gw, clean = _gateway(), _gateway()
+    now = 5 * DAY + 100
+    reqs = [Request(user=u, now=now) for u in range(8)]  # 2 panes at b=4
+
+    def broken(self, p):
+        raise RuntimeError("injected retire failure")
+
+    monkeypatch.setattr(type(gw), "_retire", broken)
+    with pytest.raises(RuntimeError, match="injected"):
+        gw.submit_many(reqs)
+    assert gw.pending == 8 and gw.requests == 0 and not gw.poll()
+    monkeypatch.undo()
+    got = gw.flush(now)
+    want = clean.submit_many(reqs)
+    assert gw.pending == 0 and gw.requests == 8
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a.response.slate, b.response.slate)
+        assert a.response.scores.tobytes() == b.response.scores.tobytes()
+
+
+@pytest.mark.parametrize("n_panes", [1, 3])
+def test_a_drain_keeps_one_pane_in_flight(n_panes):
+    """Pane k+1 is launched before pane k retires, every pane retires
+    before the call returns, and ``panes_overlapped`` counts the panes
+    launched with an earlier one unread: n - 1 of a drain of n."""
+    gw = _gateway()
+    order = []
+    launch, retire = gw._launch, gw._retire
+
+    def logged_launch(pane, gen, overlapped):
+        p = launch(pane, gen, overlapped)
+        order.append(("launch", p.pane_id))
+        return p
+
+    def logged_retire(p):
+        retire(p)
+        order.append(("retire", p.pane_id))
+
+    gw._launch, gw._retire = logged_launch, logged_retire
+    b = _ENGINE.scfg.max_batch
+    tickets = gw.submit_many([Request(user=u, now=5 * DAY + 100)
+                              for u in range(n_panes * b)])
+    assert all(t.done for t in tickets) and gw.pending == 0
+    want = [("launch", 0)]
+    for k in range(n_panes):
+        if k + 1 < n_panes:
+            want.append(("launch", k + 1))
+        want.append(("retire", k))
+    assert order == want
+    st = gw.stats()
+    assert (st.panes, st.panes_overlapped) == (n_panes, n_panes - 1)
 
 
 def test_submit_many_validates_whole_batch_before_enqueuing():

@@ -7,7 +7,9 @@ event each (gathered from the pool, injected). The trace's host plane is
 read back with ``jax.profiler.ProfileData``: the spans nest layer by
 layer on the serving thread, each pane has one span carrying its id, and
 the engine's programs run under their functions' names. Serving with the
-profiler on returns bitwise what it returns with it off.
+profiler on returns bitwise what it returns with it off. A third trace
+holds one drain of three panes, to show the launch and retire halves of
+a drain that keeps one pane in flight.
 """
 import glob
 import os
@@ -54,18 +56,40 @@ def _spans(pd):
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("trace"))
     gw = make_gateway(engine=tiny_engine(), pool_slots=8)
+    (first, second), pd, spans = _trace(
+        str(tmp_path_factory.mktemp("trace")), lambda: _serve(gw))
+    return first, second, pd, spans
+
+
+def _trace(d, serve):
     with jax.profiler.trace(d):
-        first, second = _serve(gw)
+        out = serve()
     (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
                         recursive=True)
     pd = jax.profiler.ProfileData.from_file(path)
-    return first, second, pd, _spans(pd)
+    return out, pd, _spans(pd)
+
+
+@pytest.fixture(scope="module")
+def traced_drain(tmp_path_factory):
+    """One ``submit_many`` of three full panes: a single drain."""
+    gw = make_gateway(engine=tiny_engine(), pool_slots=16)
+    b = gw.engine.scfg.max_batch
+    tickets, _, spans = _trace(
+        str(tmp_path_factory.mktemp("drain")),
+        lambda: gw.submit_many([Request(user=u, now=NOW)
+                                for u in range(3 * b)]))
+    assert all(t.done for t in tickets)
+    return spans
 
 
 def _panes(spans):
     return [s for s in spans if s[0] == "repro.gateway.pane"]
+
+
+def _retires(spans):
+    return [s for s in spans if s[0] == "repro.gateway.retire"]
 
 
 def _within(spans, pane, name):
@@ -93,9 +117,10 @@ def test_one_pane_span_per_pane_carrying_its_id(traced):
     ("repro.engine.inject", "repro.gateway.pane"),
     ("repro.engine.finalize", "repro.gateway.pane"),
     ("repro.engine.slate", "repro.gateway.pane"),
-    ("repro.engine.readback", "repro.gateway.pane"),
-    ("repro.gateway.readback", "repro.gateway.pane"),
-    ("repro.gateway.respond", "repro.gateway.pane"),
+    ("repro.engine.readback", "repro.gateway.retire"),
+    ("repro.gateway.readback", "repro.gateway.retire"),
+    ("repro.gateway.respond", "repro.gateway.retire"),
+    ("repro.gateway.retire", "repro.gateway.submit"),
     ("repro.gateway.submit", None),
     ("repro.feature.observe", None),
 ])
@@ -127,8 +152,27 @@ def test_inject_only_in_the_pane_with_a_suffix(traced):
                                   "repro.gateway.readback"])
 def test_each_pane_reads_back_once(traced, name):
     spans = traced[3]
-    for pane in _panes(spans):
-        assert len(_within(spans, pane, name)) == 1
+    retires = _retires(spans)
+    assert [r[3]["pane"] for r in retires] == [
+        p[3]["pane"] for p in _panes(spans)]
+    for retire in retires:
+        assert len(_within(spans, retire, name)) == 1
+
+
+def test_a_drain_launches_each_pane_before_the_last_one_retires(
+        traced_drain):
+    """n panes in one drain open n pane spans and n retire spans; every
+    pane but the first is launched while the one before it is unread
+    (``overlapped``), and pane k retires after pane k+1's launch."""
+    panes, retires = _panes(traced_drain), _retires(traced_drain)
+    assert [p[3]["pane"] for p in panes] == [0, 1, 2]
+    assert [r[3]["pane"] for r in retires] == [0, 1, 2]
+    assert [bool(p[3]["overlapped"]) for p in panes] == [False, True, True]
+    for k in range(2):
+        assert panes[k + 1][2] <= retires[k][1]
+    for r in retires:
+        assert r[4] == "repro.gateway.submit"
+        assert _within(traced_drain, r, "repro.engine.readback")
 
 
 def test_engine_programs_run_under_their_names(traced):
