@@ -4,6 +4,10 @@ A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; the
 harness finds them, by name, at
 
     bench/configs/<config>.json   model sizes, serving shapes, feature plane
+    bench/configs/<config>.py     the configuration's own reference and
+                                  work counts, all of them, where the
+                                  shared ones do not cover its layers
+                                  (``config_code``)
     bench/traffic/<traffic>.json  the mix's parameters (read by traffic.py)
     bench/cells/<cell>.json       the cell's arrival rate and check limit
 
@@ -14,8 +18,10 @@ receives only them.
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import types
 from typing import Any, Dict
 
 import numpy as np
@@ -85,6 +91,56 @@ def model_config(conf: Dict[str, Any]):
     return cfg
 
 
+def load_module(path: str, name: str) -> types.ModuleType:
+    """The Python file at ``path``, loaded by path as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"),
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# what a configuration's own module defines, all of them in place of the
+# shared ones: the plain float32 reference (bench/reference.py) and the
+# work counts of the calls the readers judge (bench/work.py)
+CODE_CALLS = ("logits_at", "prefill", "inject", "slate", "scatter",
+              "row_flops")
+
+
+def config_code(conf: Dict[str, Any],
+                bench_dir: str = BENCH) -> types.SimpleNamespace:
+    """The reference and work calls of the configuration ``conf`` (the
+    json of ``bench_dir``/configs/<name>.json), CODE_CALLS by name.
+
+    Where ``bench/configs/<name>.py`` exists it is loaded by path and
+    gives every call (it may import a shared one); otherwise the shared
+    calls answer, for the configurations they cover
+    (``reference.covers``). Anything else is refused here, at set-up. The
+    precisions (``reference._quant``, ``_mm``), ``work.least_time`` and
+    the check's comparison stay shared, so every configuration is judged
+    by the same yardstick."""
+    from bench import reference, work
+
+    rel = f"bench/configs/{conf['name']}.py"
+    path = os.path.join(bench_dir, "configs", conf["name"] + ".py")
+    if os.path.exists(path):
+        mod = load_module(path, "bench_config_" + conf["name"])
+        missing = [c for c in CODE_CALLS if not hasattr(mod, c)]
+    elif reference.covers(model_config(conf)):
+        mod = types.SimpleNamespace(logits_at=reference.logits_at, **{
+            c: getattr(work, c) for c in CODE_CALLS[1:]})
+        missing = []
+    else:
+        missing = list(CODE_CALLS)
+    if missing:
+        raise ValueError(
+            f"{conf['name']}: the shared reference and work counts cover "
+            f"a stack of Mamba-2 layers or of dense attention layers only, "
+            f"and a configuration's own module gives every call; {rel} "
+            f"must define {', '.join(missing)}")
+    return types.SimpleNamespace(**{c: getattr(mod, c) for c in CODE_CALLS})
+
+
 def n_items(cfg) -> int:
     """Item ids the catalog holds: token = item + 1, token 0 is padding."""
     return cfg.vocab_size - 1
@@ -99,13 +155,16 @@ def n_items(cfg) -> int:
 _ONES = ("scale", "norm_scale", "D")
 _ZEROS = ("conv_bias_x", "conv_bias_B", "conv_bias_C", "bq", "bk", "bv")
 _FAN_IN_DIMS = {"wo": 2}          # (layers, heads, head_dim, d_model)
+_EXPERT_MATS = ("gate", "up", "down")   # under "moe": (layers, experts,
+                                        # in, out), fan_in = in
 
 
-def _leaf_init(name: str, shape, key, d_model: int, cfg):
+def _leaf_init(path, shape, key, d_model: int, cfg):
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
+    name = path[-1]
     if name in _ONES:
         return jnp.ones(shape, f32)
     if name in _ZEROS:
@@ -123,8 +182,10 @@ def _leaf_init(name: str, shape, key, d_model: int, cfg):
     if name.startswith("conv_"):            # depthwise, width conv_width
         lim = shape[-2] ** -0.5
         return jax.random.uniform(key, shape, f32, -lim, lim)
-    k = _FAN_IN_DIMS.get(name, 1)
-    fan_in = int(np.prod(shape[1:1 + k]))
+    if name in _EXPERT_MATS and "moe" in path[:-1]:
+        fan_in = shape[-2]
+    else:
+        fan_in = int(np.prod(shape[1:1 + _FAN_IN_DIMS.get(name, 1)]))
     return jax.random.normal(key, shape, f32) * fan_in ** -0.5
 
 
@@ -139,13 +200,14 @@ def make_weights(cfg, seed: int, device=None):
     shapes = jax.eval_shape(lambda k: init_params(cfg, k, jnp.float32),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    names = [str(getattr(path[-1], "key", path[-1])) for path, _ in flat]
+    paths = [tuple(str(getattr(p, "key", p)) for p in path)
+             for path, _ in flat]
 
     def init(key):
         keys = jax.random.split(key, len(flat))
         return jax.tree_util.tree_unflatten(treedef, [
-            _leaf_init(n, s.shape, k, cfg.d_model, cfg)
-            for n, (_, s), k in zip(names, flat, keys)])
+            _leaf_init(p, s.shape, k, cfg.d_model, cfg)
+            for p, (_, s), k in zip(paths, flat, keys)])
 
     key = jax.random.PRNGKey(derived_seed(seed, "weights"))
     fn = jax.jit(init) if device is None else jax.jit(

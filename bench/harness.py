@@ -38,8 +38,7 @@ TRACE_MAX_S = 3.0
 # gateway, and the calls the traced run wraps in a span of its own
 LAYER_CALLS = {"engine": ("prefill", "inject", "finalize", "decode_slate"),
                "pool": ("gather", "scatter"),
-               "gateway": ("_histories", "_suffixes", "_assemble_pool",
-                           "_execute")}
+               "gateway": ("_histories", "_suffixes", "_assemble_pool")}
 
 
 def enable_compile_cache(root: str) -> str:
@@ -129,10 +128,11 @@ def warm_up(gw, conf, sched, base: int, seed: int, catalog: int):
     return users, items, ts
 
 
-def reference_check(params, cfg, conf, pr, win, seed, control=None):
+def reference_check(params, cfg, conf, code, pr, win, seed, control=None):
     """Sample the window's finished requests and compare their slates
-    with the reference. With ``control`` (a precision of reference.py)
-    the sampled requests' slates are the reference's own greedy decode
+    with the configuration's reference (``code.logits_at``, from
+    cell.config_code). With ``control`` (a precision of reference.py)
+    the sampled requests' slates are that reference's own greedy decode
     at that precision: the control put in the program's place, judged by
     the same comparison. Returns (readings dict, gaps)."""
     import jax
@@ -153,10 +153,11 @@ def reference_check(params, cfg, conf, pr, win, seed, control=None):
                            for k in idx]).astype(np.int64)
         slates = np.concatenate([slates] + [slates[:1]] * (n_rows - len(idx)))
     else:
-        slates = reference.greedy_slates(params, prompts, s["slate_len"],
-                                         length, cfg=cfg, mode=control)
+        slates = reference.greedy_slates(code.logits_at, params, prompts,
+                                         s["slate_len"], length, cfg=cfg,
+                                         mode=control)
     toks, pos = check.streams(prompts, slates, length)
-    ref = np.asarray(jax.block_until_ready(reference.logits_at(
+    ref = np.asarray(jax.block_until_ready(code.logits_at(
         params, toks, pos, cfg=cfg, mode="highest")))
     gaps = check.served_gaps(ref[:len(idx)], slates[:len(idx)])
     n_sfx = [len(p) - s["prefill_len"] for p in prompts[:len(idx)]]
@@ -183,6 +184,7 @@ def run(spec, seed: int, seconds: float, trace: bool,
         require_tpu or trace) else None
     conf, mix, cellf = spec.config, spec.traffic, spec.cell
     cfg = cell_mod.model_config(conf)
+    code = cell_mod.config_code(conf, spec.bench_dir)
     catalog = cell_mod.n_items(cfg)
     phase = {}
 
@@ -261,15 +263,15 @@ def run(spec, seed: int, seconds: float, trace: bool,
     prompts = check.Prompts(conf, plane, sched, win, warm_events)
     t = time.perf_counter()
     if reference:
-        readings, gaps = reference_check(params, cfg, conf, prompts, win,
-                                         seed)
+        readings, gaps = reference_check(params, cfg, conf, code, prompts,
+                                         win, seed)
     else:
         readings, gaps = {"rows": 0}, np.full((1, 1), np.inf)
     served_gap = float(np.max(gaps))
     if control is not None:
         log(f"served slates: widest gap {served_gap:.6g}")
-        readings, gaps = reference_check(params, cfg, conf, prompts, win,
-                                         seed, control=control)
+        readings, gaps = reference_check(params, cfg, conf, code, prompts,
+                                         win, seed, control=control)
         readings["control"] = control
     check_s = time.perf_counter() - t
     limit = cellf["logit_gap_limit"]
@@ -281,7 +283,7 @@ def run(spec, seed: int, seconds: float, trace: bool,
     result: Dict[str, Any] = {"correct": correct, "attempted": len(win.due),
                               "failed": failed}
     if trace:
-        red = tracer.reduce(cfg, conf)
+        red = tracer.reduce(cfg, conf, code)
         red["peak"] = peak_table
         red["rows"] = tracer.rows(win, prompts, conf)
         result["metrics"] = metrics_io.read_all(spec.name, red,
@@ -400,12 +402,15 @@ class _Tracer:
             out.append((not tel.cache_hit, len(prompts.prompt(int(k))) - p))
         return out
 
-    def reduce(self, cfg, conf):
+    def reduce(self, cfg, conf, code):
+        """The readers' context: the reduced trace, the counters over the
+        slice, the configuration and its work calls (``work``,
+        cell.config_code)."""
         from bench import trace as trace_mod
         try:
             red = trace_mod.reduce_dir(self.dir)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
         red["slice_counters"] = _delta(self.st[0], self.st[1])
-        red["cfg"], red["conf"] = cfg, conf
+        red["cfg"], red["conf"], red["work"] = cfg, conf, code
         return red

@@ -1,6 +1,7 @@
 """Share of the inject program's roofline: the least time the chip could
 take for one call at the served shapes (max of operations over peak and
-bytes over bandwidth, bench/work.py) over the measured time per run."""
+bytes over bandwidth: the configuration's ``inject`` work count, the
+shared ``work.least_time``) over the measured time per run."""
 from bench import work
 from bench.metrics_io import module_time
 
@@ -16,6 +17,6 @@ def read(ctx):
     if not n or t <= 0:
         return None
     s = ctx["conf"]["serving"]
-    w = work.inject(ctx["cfg"], s["max_batch"], s["inject_len"],
-                    s["prefill_len"])
+    w = ctx["work"].inject(ctx["cfg"], s["max_batch"], s["inject_len"],
+                           s["prefill_len"])
     return 100.0 * work.least_time(w, ctx["peak"]) / (t / n)

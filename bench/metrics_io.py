@@ -9,13 +9,15 @@ quantity split by the end-to-end metric it moves). A reader declares
 ``MOVES``; what it declares must agree with ``BENCHMARK.json``. It
 defines ``read(ctx)``. ``ctx`` is the reduced trace (trace.py) with the
 gateway's counters over the traced slice and the whole window, the
-configuration, the peaks of the device and the rows served in the slice.
+configuration (``cfg``, ``conf``) and its work calls (``work``:
+``prefill``, ``inject``, ``slate``, ``scatter``, ``row_flops``, as
+cell.config_code gives them), the peaks of the device and the rows
+served in the slice.
 A reader that finds nothing to read returns None, and the metric is left
 out of the result line.
 """
 from __future__ import annotations
 
-import importlib.util
 import os
 from typing import Any, Dict, List
 
@@ -38,12 +40,8 @@ def reader_path(name: str, bench_dir: str = BENCH) -> str:
 
 
 def load_reader(name: str, bench_dir: str = BENCH):
-    path = reader_path(name, bench_dir)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    from bench.cell import load_module
+    return load_module(reader_path(name, bench_dir), "bench_metric_" + name)
 
 
 def read_all(cell: str, ctx: Dict[str, Any], bm: Dict[str, Any],

@@ -22,6 +22,11 @@ row's last token cannot reach earlier positions of a causal model.
 "fp8" is the control: the reference put one precision below what the
 configurations state they serve at (one bfloat16 pass), decoding its own
 slates (``greedy_slates``) in the program's place.
+
+A configuration that these two stacks do not cover (``covers``) brings
+its own reference and work counts in ``bench/configs/<name>.py``
+(cell.config_code), its ``logits_at`` built from ``_mm``, ``_quant``,
+``_rms`` and ``_conv`` so that each mode means the same for every model.
 """
 from __future__ import annotations
 
@@ -132,17 +137,28 @@ def _attn_layer(x, lp, cfg, mode):
     return x + _mm("blf,fd->bld", g * u, m["down"], mode)
 
 
+def covers(cfg) -> bool:
+    """Whether this module (and bench/work.py) covers ``cfg``: every layer
+    a Mamba-2 mixer alone (family ssm), or every layer causal attention
+    with RoPE and a dense SwiGLU (family dense), with none of the options
+    the reference leaves out. Any other configuration brings its own
+    reference (cell.config_code)."""
+    kinds = set(zip(cfg.layer_kinds(), cfg.mlp_kinds()))
+    plain = (not cfg.qkv_bias and not cfg.sliding_window
+             and cfg.frontend == "none")
+    return plain and ((cfg.family == "ssm" and kinds == {("ssm", "none")})
+                      or (cfg.family == "dense"
+                          and kinds == {("attn", "dense")}))
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "mode"))
 def logits_at(params, tokens, pos, *, cfg, mode="highest"):
     """tokens (k, L) int32, right-padded; pos (k, T) positions to read.
     Returns (k, T, vocab_size) float32 logits of the next token after
     each position."""
-    if cfg.family == "ssm":
-        layer = _mamba_layer
-    elif cfg.family == "dense" and cfg.moe is None and cfg.ssm is None:
-        layer = _attn_layer
-    else:
+    if not covers(cfg):
         raise NotImplementedError(f"no reference for {cfg.name}")
+    layer = _mamba_layer if cfg.family == "ssm" else _attn_layer
     table = params["embed"]["table"]
     x = table[tokens]
     blocks = params["blocks"]
@@ -159,10 +175,12 @@ def logits_at(params, tokens, pos, *, cfg, mode="highest"):
     return _mm("btd,vd->btv", x, head[:cfg.vocab_size], mode)
 
 
-def greedy_slates(params, prompts, slate_len, length, *, cfg, mode):
-    """(k, slate_len) slates decoded greedily by the reference at
-    ``mode``: each pick is the best token not yet in its slate, given the
-    prompt and the picks before it (one pass over the streams a pick)."""
+def greedy_slates(logits_fn, params, prompts, slate_len, length, *, cfg,
+                  mode):
+    """(k, slate_len) slates decoded greedily by ``logits_fn`` (a
+    configuration's ``logits_at``) at ``mode``: each pick is the best
+    token not yet in its slate, given the prompt and the picks before it
+    (one pass over the streams a pick)."""
     k = len(prompts)
     lens = np.asarray([len(p) for p in prompts])
     toks = np.zeros((k, length), np.int32)
@@ -173,7 +191,7 @@ def greedy_slates(params, prompts, slate_len, length, *, cfg, mode):
     taken = np.zeros((k, cfg.vocab_size), bool)
     for j in range(slate_len):
         pos = (lens - 1 + j)[:, None].astype(np.int32)
-        lg = np.asarray(logits_at(params, toks, pos, cfg=cfg, mode=mode))
+        lg = np.asarray(logits_fn(params, toks, pos, cfg=cfg, mode=mode))
         picks[:, j] = np.where(taken, -np.inf, lg[:, 0]).argmax(-1)
         taken[rows, picks[:, j]] = True
         if j + 1 < slate_len:

@@ -53,6 +53,7 @@ def test_cells_have_their_files():
         assert spec.cell["rate_per_s"] > 0
         assert spec.cell["logit_gap_limit"] > 0
         cell.model_config(spec.config)        # matches the registry
+        cell.config_code(spec.config)         # a reference covers it
         pairs.add((w["config"], w["traffic"]))
     assert len(pairs) == len(BM["workloads"])
     used = {w["config"] for w in BM["workloads"]}
@@ -142,7 +143,8 @@ def _ctx(empty=False):
             "hand_s": 0.0 if empty else 1.0,
             "busy_hand_s": 0.0 if empty else 0.6,
             "rows": [] if empty else [(True, 16)] * 8 + [(False, 16)] * 72,
-            "cfg": cfg, "conf": conf, "peak": peak}
+            "cfg": cfg, "conf": conf, "work": cell.config_code(conf),
+            "peak": peak}
 
 
 def test_eager_operations_beside_a_program_are_left_out():

@@ -13,11 +13,19 @@ around the program's layers (``bench.engine.inject``,
 ``bench.pool.scatter``, ...). All times are on one clock, in
 nanoseconds.
 
-Programs are named by what launched them: the n-th run of a program on
-the device is the n-th launch on the host (one stream, in order), and a
-run is labelled ``<module>@<span>`` with the innermost layer span around
-its launch (``jit__unknown@engine.inject``: the engine jits
-``functools.partial`` objects, which XLA names ``jit__unknown``).
+Programs are named by what launched them: a launch lies inside jaxlib's
+span of the jitted call that made it (``PjitFunction(_inject_impl)``, on
+the Python thread's line, while the TPU runtime records the launch on a
+line of its own), which names the program it runs (``jit__inject_impl``);
+the n-th run of a program on the device is the n-th launch of that
+program on the host (one stream, in order), and a run is labelled
+``<module>@<span>`` with the innermost layer span around its launch
+(``jit__inject_impl@engine.inject``). A launch the trace does not name is
+matched in order among the unnamed. A small program can start on the
+device as soon as it is launched, and the two clocks can disagree by
+more than the slack: such a run misses its launch, and matched in order
+over all programs every run after it would take the launch before its
+own.
 
 Within the traced slice (the ``bench.slice`` span):
 
@@ -30,15 +38,17 @@ Within the traced slice (the ``bench.slice`` span):
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
-from collections import defaultdict
-from typing import Dict, Iterable, List, Sequence, Tuple
+from collections import defaultdict, deque
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]
 
 LAUNCH = "PJRT_LoadedExecutable_Execute"
+CALL = "PjitFunction("     # jaxlib's span of a jitted call: "PjitFunction(f)"
 LAYER_SPANS = ("bench.engine.", "bench.pool.")
 MODULE_LINE = "XLA Modules"
 OP_LINE = "XLA Ops"
@@ -134,19 +144,37 @@ def innermost(spans, t: float, prefixes: Sequence[str]):
     return best
 
 
+def call_at(calls: Sequence[Tuple[float, float, str]], t: float):
+    """Program of the innermost jitted call (start, end, program) holding
+    ``t``; ``calls`` sorted by start."""
+    for i in range(bisect.bisect_right(calls, (t, float("inf"), "")) - 1,
+                   -1, -1):
+        if calls[i][1] > t:
+            return calls[i][2]
+    return None
+
+
 def label_modules(modules, launches, spans):
-    """(label, start, end) per module run: each run matched, in order, to
-    the earliest unmatched launch no later than its start (plus clock
-    slack), and labelled with the layer span around that launch."""
-    launches = sorted(launches)
-    out, j = [], 0
+    """(label, start, end) per module run: each run matched to the earliest
+    unmatched launch of its program (of the unnamed launches, where the
+    trace names none of its program; a launch named after no module run
+    counts as unnamed) no later than its start (plus clock slack), and
+    labelled with the layer span around that launch. A run
+    that misses its own launch (launched before the trace began, or
+    stamped before it by more than the slack) moves the matches of its
+    own program only, never another program's."""
+    programs = {module_name(m[0]) for m in modules}
+    queues: Dict[Optional[str], deque] = defaultdict(deque)
+    for t, program in sorted(launches, key=lambda launch: launch[0]):
+        queues[program if program in programs else None].append(t)
+    out = []
     for name, a, b in sorted(modules, key=lambda m: m[1]):
         label = module_name(name)
-        if j < len(launches) and launches[j] <= a + CLOCK_SLACK_NS:
-            span = innermost(spans, launches[j], LAYER_SPANS)
+        queue = queues[label if label in queues else None]
+        if queue and queue[0] <= a + CLOCK_SLACK_NS:
+            span = innermost(spans, queue.popleft(), LAYER_SPANS)
             if span is not None:
                 label += "@" + span[len("bench."):]
-            j += 1
         out.append((label, a, b))
     return out
 
@@ -169,11 +197,11 @@ def charge_idle(idle: Sequence[Interval], spans) -> Dict[str, float]:
 def reduce(slice_iv: Interval, host: Sequence[Tuple[str, float, float]],
            ops: Sequence[Interval],
            modules: Sequence[Tuple[str, float, float]],
-           launches: Sequence[float] = ()) -> Dict:
+           launches: Sequence[Tuple[float, Optional[str]]] = ()) -> Dict:
     """Device numbers of one traced slice. ``host``: (name, start, end) of
     the harness's spans (one thread); ``ops`` and ``modules`` of one
-    device; ``launches``: host start of each program launch. Seconds
-    out, nanoseconds in."""
+    device; ``launches``: (host start, program or None) of each program
+    launch. Seconds out, nanoseconds in."""
     lo, hi = slice_iv
     modules = label_modules(modules, launches, nest(host))
     spans = nest([s for s in host if s[1] < hi and s[2] > lo])
@@ -207,12 +235,14 @@ def reduce(slice_iv: Interval, host: Sequence[Tuple[str, float, float]],
 
 
 def load(path: str):
-    """(harness spans, launch starts, {device plane: (ops, modules)}) from
-    one xplane file."""
+    """(harness spans, launches, {device plane: (ops, modules)}) from one
+    xplane file; a launch is (host start, program), the program named by
+    the jitted call around it in time (``jit_<function>``, as XLA names
+    the module), or None."""
     import jax
 
     pd = jax.profiler.ProfileData.from_file(path)
-    host, launches, devices = [], [], {}
+    host, starts, calls, devices = [], [], [], {}
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
             ops, modules = [], []
@@ -232,8 +262,12 @@ def load(path: str):
                         host.append((e.name, e.start_ns,
                                      e.start_ns + e.duration_ns))
                     elif e.name == LAUNCH:
-                        launches.append(e.start_ns)
-    return host, launches, devices
+                        starts.append(e.start_ns)
+                    elif e.name.startswith(CALL) and e.name.endswith(")"):
+                        calls.append((e.start_ns, e.start_ns + e.duration_ns,
+                                      "jit_" + e.name[len(CALL):-1]))
+    calls.sort()
+    return host, [(t, call_at(calls, t)) for t in starts], devices
 
 
 def reduce_dir(trace_dir: str) -> Dict:
