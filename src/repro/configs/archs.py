@@ -93,6 +93,26 @@ JAMBA_52B = register(ModelConfig(
     source="Mamba+attn 1:7 interleave, MoE [arXiv:2403.19887]",
 ))
 
+# Granite 4.0-H Small (granitemoehybrid): 36 Mamba-2 mixers and 4 NoPE GQA
+# mixers (layers 5, 15, 25, 35), an MoE block on every layer (72 experts
+# of width 768 routed top-10, softmax over the top 10, plus a shared
+# SwiGLU expert of width 1536), Granite's four multipliers. The expert
+# width is config.json's intermediate_size (an inference: it has no key
+# of its own); dt_min/dt_max are the SSD defaults (not in config.json).
+GRANITE_4_H_SMALL = register(ModelConfig(
+    name="granite-4.0-h-small", family="hybrid",
+    n_layers=40, d_model=4096, n_heads=32, n_kv_heads=8, d_ff=768,
+    vocab_size=100352, head_dim=128, norm_eps=1e-5, rope_theta=10000.0,
+    tie_embeddings=True, position_embedding="none",
+    ssm=SSMConfig(d_state=128, expand=2, head_dim=64, conv_width=4,
+                  chunk_size=256, attn_period=10, attn_offset=5),
+    moe=MoEConfig(n_experts=72, top_k=10, period=1, shared_d_ff=1536),
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    attention_multiplier=0.0078125, logits_scaling=16.0,
+    source="Granite 4.0-H Small 32B-A9B "
+           "[hf:ibm-granite/granite-4.0-h-small]",
+))
+
 # ---------------------------------------------------------------- paper
 # The paper's own production ranker is unspecified; we use a SASRec-class
 # sequential ranker over the item vocabulary — small enough to train for a

@@ -9,7 +9,10 @@ Layer-type schedule
 ``layer_kinds()`` returns, per layer, one of ``"attn"`` / ``"ssm"`` — the
 sequence-mixing block — and ``mlp_kinds()`` one of ``"dense"`` / ``"moe"``.
 This single mechanism expresses dense transformers, MoE transformers, pure
-SSMs (mamba2) and the Jamba hybrid (attn:mamba 1:7, MoE every other layer).
+SSMs (mamba2), the Jamba hybrid (attn:mamba 1:7, MoE every other layer)
+and Granite 4.0-H (attn:mamba 1:9 with NoPE attention, MoE with a shared
+expert on every layer, Granite's embedding/residual/attention/logits
+multipliers).
 """
 from __future__ import annotations
 
@@ -38,10 +41,23 @@ class MoEConfig:
     # Train default 1.25 (GShard-style dropping); set to n_experts/top_k
     # (or use ``no_drop()``) for drop-free eval/serving.
     capacity_factor: float = 1.25
+    # expert parallelism: the router scores ``router_experts`` experts (0
+    # = ``n_experts``) and this layer holds ``n_experts`` of them, router
+    # ids [expert_offset, expert_offset + n_experts); the others' part of
+    # the result is computed by the chips that hold them
+    router_experts: int = 0
+    expert_offset: int = 0
+    # width of a SwiGLU expert every token passes through beside the
+    # routed ones (0 = none)
+    shared_d_ff: int = 0
+
+    @property
+    def n_router(self) -> int:
+        return self.router_experts or self.n_experts
 
     def no_drop(self) -> "MoEConfig":
         import dataclasses as _dc
-        return _dc.replace(self, capacity_factor=float(self.n_experts) / self.top_k)
+        return _dc.replace(self, capacity_factor=float(self.n_router) / self.top_k)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +98,16 @@ class ModelConfig:
     frontend: str = "none"  # none | vision | audio
     # citation for the architecture source
     source: str = ""
+    # "rope" | "none" (NoPE: attention sees no positions)
+    position_embedding: str = "rope"
+    # Granite-style scalars, each a no-op at its default: the embedding is
+    # multiplied by embedding_multiplier, each residual branch by
+    # residual_multiplier, the logits divided by logits_scaling; the
+    # softmax scale is attention_multiplier (0 = head_dim ** -0.5)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     # ------------------------------------------------------------------
     @property
@@ -159,19 +185,23 @@ class ModelConfig:
                 total += 3 * d * self.d_ff  # gate, up, down (swiglu)
             elif mlp == "moe":
                 m = self.moe
-                total += d * m.n_experts  # router
+                total += d * m.n_router  # router, at its full width
                 total += m.n_experts * 3 * d * self.d_ff
+                total += 3 * d * m.shared_d_ff  # shared expert
         total += d  # final norm
         return total
 
     def active_param_count(self) -> int:
-        """Parameters active per token (MoE top-k instead of all experts)."""
+        """Parameters active per token (MoE top-k instead of all experts;
+        of a share of the experts, the top-k's expected share of it)."""
         if self.moe is None:
             return self.param_count()
         m = self.moe
         total = self.param_count()
         n_moe_layers = sum(1 for k in self.mlp_kinds() if k == "moe")
-        inactive = n_moe_layers * (m.n_experts - m.top_k) * 3 * self.d_model * self.d_ff
+        active = m.top_k * m.n_experts / m.n_router
+        inactive = int(n_moe_layers * (m.n_experts - active) * 3
+                       * self.d_model * self.d_ff)
         return total - inactive
 
     def validate(self) -> None:
@@ -181,7 +211,11 @@ class ModelConfig:
             assert self.n_heads * self.head_dim_ >= 1
             assert self.n_heads % self.n_kv_heads == 0, "GQA group must be integral"
         if self.moe is not None:
-            assert self.moe.top_k <= self.moe.n_experts
+            m = self.moe
+            assert m.top_k <= m.n_router
+            assert 0 <= m.expert_offset
+            assert m.expert_offset + m.n_experts <= m.n_router
+        assert self.position_embedding in ("rope", "none")
 
 
 # ----------------------------------------------------------------------
@@ -224,7 +258,8 @@ def reduced(cfg: ModelConfig, n_layers: int = 2, d_model: int = 256,
     if cfg.moe is not None:
         moe = dataclasses.replace(
             cfg.moe, n_experts=min(cfg.moe.n_experts, max_experts),
-            top_k=min(cfg.moe.top_k, 2))
+            top_k=min(cfg.moe.top_k, 2), router_experts=0, expert_offset=0,
+            shared_d_ff=min(cfg.moe.shared_d_ff, 2 * d_model))
     ssm = None
     if cfg.ssm is not None:
         ssm = dataclasses.replace(

@@ -48,7 +48,8 @@ def init_attention(kg: KeyGen, cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict[str
 
 
 def _project_qkv(params, x, positions, cfg: ModelConfig):
-    """x (B,S,d) -> q (B,S,nq,hd), k/v (B,S,nkv,hd); q,k RoPE-rotated."""
+    """x (B,S,d) -> q (B,S,nq,hd), k/v (B,S,nkv,hd); q,k RoPE-rotated
+    unless the config has no position embedding."""
     q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"])
     k = jnp.einsum("bsd,dnh->bsnh", x, params["wk"])
     v = jnp.einsum("bsd,dnh->bsnh", x, params["wv"])
@@ -56,9 +57,14 @@ def _project_qkv(params, x, positions, cfg: ModelConfig):
         q = q + params["bq"]
         k = k + params["bk"]
         v = v + params["bv"]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _scale(cfg: ModelConfig) -> float:
+    return cfg.attention_multiplier or cfg.head_dim_ ** -0.5
 
 
 def _repeat_kv(k, g):
@@ -128,7 +134,7 @@ def attention_full(params, x, positions, cfg: ModelConfig, *,
     b, s, d = x.shape
     nkv, hd = cfg.n_kv_heads, cfg.head_dim_
     g = cfg.n_heads // nkv
-    scale = hd ** -0.5
+    scale = _scale(cfg)
     q, k, v = _project_qkv(params, x, positions, cfg)
 
     qpos_full = positions  # (B,S) or (S,)
@@ -260,7 +266,7 @@ def attention_decode(params, x, pos, cache, cfg: ModelConfig,
     nkv, hd = cfg.n_kv_heads, cfg.head_dim_
     g = cfg.n_heads // nkv
     w = cache["k"].shape[1]
-    scale = hd ** -0.5
+    scale = _scale(cfg)
 
     q, k_new, v_new = _project_qkv(params, x, pos[:, None], cfg)
     slot = (pos % w).astype(jnp.int32)

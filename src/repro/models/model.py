@@ -2,7 +2,8 @@
 
 Layer-stack execution uses **pattern scan**: the per-layer (mixer, mlp) kind
 sequence of every assigned arch is periodic — period 1 for uniform stacks,
-period 8 for Jamba's attn:mamba 1:7 interleave — so parameters are stored
+period 8 for Jamba's attn:mamba 1:7 interleave, period 10 for Granite
+4.0-H's 1:9 — so parameters are stored
 stacked as ``blocks["pos{p}"]`` with leading dim R = n_layers / P and the
 stack runs as a single ``lax.scan`` over R repeats (compile time stays flat
 in depth: deepseek-67b's 95 layers lower as 1 scan, not 95 inlined blocks).
@@ -114,25 +115,28 @@ def _apply_sublayer(lp, x, *, cfg, kind, mlp_kind, mode, cache, positions,
     """Returns (x, cache_out, aux)."""
     h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
     if kind == "attn":
-        if mode == "decode":
-            mix, cache_out = attn_mod.attention_decode(
-                lp["attn"], h, positions, cache, cfg)
-        else:
-            mix, kv = attn_mod.attention_full(
-                lp["attn"], h, positions, cfg, valid=valid,
-                prefix_kv=cache if mode == "extend" else None,
-                prefix_valid=prefix_valid, q_chunk=q_chunk,
-                head_pad_to=head_pad_to, attn_sharding=attn_sharding)
-            cache_out = kv if mode in ("prefill", "extend") else None
+        with jax.named_scope("attn"):
+            if mode == "decode":
+                mix, cache_out = attn_mod.attention_decode(
+                    lp["attn"], h, positions, cache, cfg)
+            else:
+                mix, kv = attn_mod.attention_full(
+                    lp["attn"], h, positions, cfg, valid=valid,
+                    prefix_kv=cache if mode == "extend" else None,
+                    prefix_valid=prefix_valid, q_chunk=q_chunk,
+                    head_pad_to=head_pad_to, attn_sharding=attn_sharding)
+                cache_out = kv if mode in ("prefill", "extend") else None
     else:  # ssm
-        if mode == "decode":
-            mix, cache_out = ssm_mod.ssm_decode(lp["ssm"], h, cache, cfg)
-        else:
-            mix, state = ssm_mod.ssm_forward(
-                lp["ssm"], h, cfg, cache=cache if mode == "extend" else None,
-                use_kernel=use_kernels, valid=valid)
-            cache_out = state if mode in ("prefill", "extend") else None
-    x = x + mix
+        with jax.named_scope("ssm"):
+            if mode == "decode":
+                mix, cache_out = ssm_mod.ssm_decode(lp["ssm"], h, cache, cfg)
+            else:
+                mix, state = ssm_mod.ssm_forward(
+                    lp["ssm"], h, cfg,
+                    cache=cache if mode == "extend" else None,
+                    use_kernel=use_kernels, valid=valid)
+                cache_out = state if mode in ("prefill", "extend") else None
+    x = x + _residual(cfg, mix)
 
     aux = jnp.zeros((), jnp.float32)
     if mlp_kind != "none":
@@ -140,10 +144,20 @@ def _apply_sublayer(lp, x, *, cfg, kind, mlp_kind, mode, cache, positions,
         if mlp_kind == "dense":
             out = mlp(lp["mlp"], h)
         else:
+            # serving (prefill / extend / decode) never drops a token: a
+            # cached prefill plus an extend must equal the full prefill
             out, aux = moe_apply(lp["moe"], h, cfg, rng=moe_rng,
-                                 moe_sharding=moe_sharding)
-        x = x + out
+                                 moe_sharding=moe_sharding,
+                                 drop_free=mode != "forward")
+        x = x + _residual(cfg, out)
     return x, cache_out, aux
+
+
+def _residual(cfg: ModelConfig, branch):
+    """A residual branch scaled by ``residual_multiplier`` (no op at 1)."""
+    if cfg.residual_multiplier == 1.0:
+        return branch
+    return branch * cfg.residual_multiplier
 
 
 # ----------------------------------------------------------------------
@@ -221,6 +235,8 @@ def _embed(params, cfg, tokens, prefix_embeds, embed_mesh=None):
             lambda tbl, tok: tbl[tok], mesh=embed_mesh,
             in_specs=(PS(None, dspec), PS(bspec, None)),
             out_specs=PS(bspec, None, dspec))(table, tokens)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     return x
@@ -235,6 +251,8 @@ def _logits(params, cfg: ModelConfig, x, head_sharding=None):
         # logits come out vocab-sharded (cheap: table bytes ≪ logits bytes)
         table = jax.lax.with_sharding_constraint(table, head_sharding)
     logits = jnp.einsum("bsd,vd->bsv", x, table).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.vocab_padded != cfg.vocab_size:
         vmask = jnp.arange(cfg.vocab_padded) < cfg.vocab_size
         logits = jnp.where(vmask[None, None, :], logits, NEG_INF)

@@ -8,6 +8,21 @@ parallelism over an explicit axis is a perf-iteration variant).
 
 FLOPs are proportional to E·C = S·top_k·capacity_factor — i.e. faithful to
 the *active* parameter count, which is what the roofline compares against.
+
+**Expert share.** A layer holds ``n_experts`` of the router's
+``n_router`` experts, router ids ``[expert_offset, expert_offset +
+n_experts)``: it routes every token over all of them (published top-k,
+weights renormalised over the top-k) and computes only its own experts'
+part of the result. The other experts' part belongs to the chips that
+hold them; on one chip it is simply absent (no exchange is made).
+
+**Serving never drops a token** (``drop_free``): a buffer of ``S`` slots
+per expert holds every token, since a token's top-k are distinct
+experts, so a cached prefill plus an extend equals the full prefill
+whatever ``capacity_factor`` the config carries for training.
+
+A ``shared_d_ff`` expert (SwiGLU) runs on every token beside the routed
+ones and is added to their result.
 """
 from __future__ import annotations
 
@@ -18,25 +33,42 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import KeyGen, cdiv, scaled_init
+from repro.models.mlp import mlp
+
 
 def init_moe(kg: KeyGen, cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict[str, Any]:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.n_experts
-    return {
-        "router": scaled_init(kg(), (d, e), d, jnp.float32),
+    m = cfg.moe
+    d, f, e = cfg.d_model, cfg.d_ff, m.n_experts
+    p = {
+        "router": scaled_init(kg(), (d, m.n_router), d, jnp.float32),
         "gate": scaled_init(kg(), (e, d, f), d, dtype),
         "up": scaled_init(kg(), (e, d, f), d, dtype),
         "down": scaled_init(kg(), (e, f, d), f, dtype),
     }
+    if m.shared_d_ff:
+        fs = m.shared_d_ff
+        p["shared_gate"] = scaled_init(kg(), (d, fs), d, dtype)
+        p["shared_up"] = scaled_init(kg(), (d, fs), d, dtype)
+        p["shared_down"] = scaled_init(kg(), (fs, d), fs, dtype)
+    return p
 
 
-def moe_capacity(seq: int, cfg: ModelConfig) -> int:
+def moe_capacity(seq: int, cfg: ModelConfig, drop_free: bool = False) -> int:
+    """Slots per expert per batch row: ``seq`` when no token may drop,
+    else the expected load ``seq * top_k / n_router`` times the
+    capacity factor."""
+    if drop_free:
+        return seq
     m = cfg.moe
-    return max(1, cdiv(int(seq * m.top_k * m.capacity_factor), m.n_experts))
+    return max(1, cdiv(int(seq * m.top_k * m.capacity_factor), m.n_router))
 
 
 def moe_apply(params, x, cfg: ModelConfig, *, rng=None, moe_sharding=None,
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              drop_free: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B,S,d) -> (out (B,S,d), load-balance aux loss scalar).
+
+    ``drop_free``: every token reaches each of its held top-k experts
+    (serving); otherwise the capacity factor may drop tokens (training).
 
     ``moe_sharding``: optional (up_sharding, down_sharding) NamedShardings
     applied to the expert weights at USE time. Perf iteration (§Perf,
@@ -47,11 +79,10 @@ def moe_apply(params, x, cfg: ModelConfig, *, rng=None, moe_sharding=None,
     once per layer, compute locally.
     """
     m = cfg.moe
-    b, s, d = x.shape
-    e, k = m.n_experts, m.top_k
-    c = moe_capacity(s, cfg)
+    k = m.top_k
+    c = moe_capacity(x.shape[1], cfg, drop_free)
     gate_w, up_w, down_w = params["gate"], params["up"], params["down"]
-    ep_split = 0
+    ep_sharding, ep_split = None, 0
     if isinstance(moe_sharding, tuple) and moe_sharding[0] == "ep":
         # all-to-all expert parallelism with f-splitting (§Perf, mixtral):
         # e experts × m f-shards = tp "expert-shards"; dispatch moves to
@@ -64,16 +95,49 @@ def moe_apply(params, x, cfg: ModelConfig, *, rng=None, moe_sharding=None,
         up_w = jax.lax.with_sharding_constraint(up_w, up_sh)
         down_w = jax.lax.with_sharding_constraint(down_w, down_sh)
 
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), params["router"])
-    if rng is not None and m.router_jitter > 0:
-        logits = logits + m.router_jitter * jax.random.normal(rng, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)  # (B,S,E)
-    top_p, top_e = jax.lax.top_k(probs, k)  # (B,S,K)
-    top_w = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            params["router"])
+        if rng is not None and m.router_jitter > 0:
+            logits = logits + m.router_jitter * jax.random.normal(
+                rng, logits.shape)
+        probs = jax.nn.softmax(logits, axis=-1)  # (B,S,E)
+        top_p, top_e = jax.lax.top_k(probs, k)  # (B,S,K)
+        top_w = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
+    y = _routed(x, top_e, top_w, (gate_w, up_w, down_w), cfg, c,
+                ep_sharding, ep_split)
+    if m.shared_d_ff:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp({"gate": params["shared_gate"],
+                         "up": params["shared_up"],
+                         "down": params["shared_down"]}, x)
+
+    # ---- Switch-style load balance loss ------------------------------
+    assign = jax.nn.one_hot(top_e[..., 0], m.n_router,
+                            dtype=jnp.float32)  # top-1 fraction
+    f_e = assign.mean(axis=(0, 1))
+    p_e = probs.mean(axis=(0, 1))
+    aux = m.n_router * jnp.sum(f_e * p_e) * m.load_balance_coef
+    return y, aux
+
+
+@jax.named_scope("moe.experts")
+def _routed(x, top_e, top_w, weights, cfg: ModelConfig, c: int,
+            ep_sharding, ep_split: int):
+    """The held experts' part of the result: tokens sorted into ``c``
+    slots per expert, each expert's SwiGLU, weighted sum back."""
+    b, s, d = x.shape
+    e, k = cfg.moe.n_experts, cfg.moe.top_k
+    held = e != cfg.moe.n_router  # a share of the router's experts
+    gate_w, up_w, down_w = weights
     # ---- sort-based dispatch, per batch row --------------------------
     sk = s * k
     e_flat = top_e.reshape(b, sk)
+    if held:
+        # router ids -> held ids; an expert held elsewhere sorts last, as e
+        e_flat = e_flat - cfg.moe.expert_offset
+        e_flat = jnp.where((e_flat >= 0) & (e_flat < e), e_flat, e)
     order = jnp.argsort(e_flat, axis=-1)  # stable
     sorted_e = jnp.take_along_axis(e_flat, order, axis=-1)
     # position within each expert's run of the sorted id list
@@ -83,6 +147,8 @@ def moe_apply(params, x, cfg: ModelConfig, *, rng=None, moe_sharding=None,
     run_start = jax.lax.cummax(jnp.where(is_start, idx, 0), axis=1)
     pos_in_expert = idx - run_start
     dropped = pos_in_expert >= c
+    if held:
+        dropped |= sorted_e == e
     dest = jnp.where(dropped, e * c, sorted_e * c + pos_in_expert)  # overflow bin
 
     tok_idx = order // k  # (B, SK) source token of each sorted (token,k) pair
@@ -121,12 +187,5 @@ def moe_apply(params, x, cfg: ModelConfig, *, rng=None, moe_sharding=None,
         [expert_out.reshape(b, e * c, d), jnp.zeros((b, 1, d), x.dtype)], axis=1)
     y_sorted = jnp.take_along_axis(flat, dest[..., None], axis=1)  # (B,SK,d)
     y_flat = jnp.zeros((b, sk, d), x.dtype).at[brow, order].set(y_sorted)
-    y = (y_flat.reshape(b, s, k, d) *
-         top_w[..., None].astype(x.dtype)).sum(axis=2)
-
-    # ---- Switch-style load balance loss ------------------------------
-    assign = jax.nn.one_hot(top_e[..., 0], e, dtype=jnp.float32)  # top-1 fraction
-    f_e = assign.mean(axis=(0, 1))
-    p_e = probs.mean(axis=(0, 1))
-    aux = e * jnp.sum(f_e * p_e) * m.load_balance_coef
-    return y, aux
+    return (y_flat.reshape(b, s, k, d) *
+            top_w[..., None].astype(x.dtype)).sum(axis=2)

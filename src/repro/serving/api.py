@@ -247,7 +247,9 @@ class GatewayStats:
     paths: Dict[str, int]     # "prefill"/"inject"/"cached"/"decay" rows
     queue_delay: Dict[str, float]  # window/p50/p99/max over recent requests
     rollover: RolloverStats
-    cache: Dict[str, int]     # PrefillStateCache / PagedStateCache counters
+    # PrefillStateCache / PagedStateCache counters (the pool's adds
+    # slot_bytes_by_kind: one slot's ssm / kv / logits / mask bytes)
+    cache: Dict[str, Any]
     # tiered EventLog ingest counters (EventLog.ingest_stats()):
     # appended/events_hot/events_warm/bytes_hot/bytes_warm/demoted/
     # dropped_late/trimmed/evicted/compactions/segments/hot_overflow

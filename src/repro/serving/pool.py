@@ -143,6 +143,21 @@ def _scatter_impl(caches, valid, next_pos, last,
             _upd(last, st_logits[:, -1, :], onehot, covered, 0))
 
 
+def _bytes_by_kind(cfg, caches, valid, next_pos, last, n_slots: int,
+                   ) -> Dict[str, int]:
+    """One slot's bytes by kind of state: ``ssm`` (conv tails and
+    recurrent state of the SSM layers), ``kv`` (keys and values of the
+    attention layers), ``logits`` (the pre-inject next-item scores) and
+    ``mask`` (the prefill pad mask and next position)."""
+    from repro.models.model import pattern_sig
+    out = {"ssm": 0, "kv": 0, "logits": last.nbytes // n_slots,
+           "mask": (valid.nbytes + next_pos.nbytes) // n_slots}
+    for p, (kind, _) in enumerate(pattern_sig(cfg)):
+        out["kv" if kind == "attn" else "ssm"] += sum(
+            x.nbytes for x in jax.tree.leaves(caches[f"pos{p}"])) // n_slots
+    return out
+
+
 # ----------------------------------------------------------------------
 # The pool
 # ----------------------------------------------------------------------
@@ -213,10 +228,10 @@ class DeviceStatePool:
                               None if mesh is None else self._rows_ns)
         self.last_logits = alloc((self.n_slots, vp), logits_s.dtype,
                                  None if mesh is None else self._logits_ns)
-        self.slot_nbytes = sum(
-            x.nbytes for x in jax.tree.leaves(
-                (self.caches, self.valid, self.next_pos, self.last_logits))
-        ) // self.n_slots
+        self.slot_bytes_by_kind = _bytes_by_kind(
+            engine.cfg, self.caches, self.valid, self.next_pos,
+            self.last_logits, self.n_slots)
+        self.slot_nbytes = sum(self.slot_bytes_by_kind.values())
 
         if mesh is None:
             # no mesh -> no partitioning constraint: gather by direct
@@ -552,4 +567,5 @@ class PagedStateCache:
                 "shards": self.shards,
                 "slots": self.pool.n_slots,
                 "free_slots": len(self._free),
-                "slot_bytes": self.pool.slot_nbytes}
+                "slot_bytes": self.pool.slot_nbytes,
+                "slot_bytes_by_kind": dict(self.pool.slot_bytes_by_kind)}

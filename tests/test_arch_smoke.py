@@ -22,6 +22,9 @@ from repro.training.optimizer import AdamWConfig, init_opt_state
 from repro.training.train_loop import TrainConfig, make_train_step
 
 B, S = 2, 32
+# the assigned ten and the benchmark's granite-4.0-h-small (NoPE
+# attention, Mamba-2, MoE with a shared expert, Granite's multipliers)
+ARCHS = ASSIGNED + ("granite-4.0-h-small",)
 
 
 def _setup(arch):
@@ -41,7 +44,7 @@ def _prefix(cfg, n=4):
     return None
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
+@pytest.mark.parametrize("arch", ARCHS)
 def test_forward_shapes_no_nan(arch):
     cfg, params, toks = _setup(arch)
     pfx = _prefix(cfg)
@@ -52,7 +55,7 @@ def test_forward_shapes_no_nan(arch):
     assert not jnp.isnan(aux)
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
+@pytest.mark.parametrize("arch", ARCHS)
 def test_one_train_step(arch):
     cfg, params, toks = _setup(arch)
     pfx = _prefix(cfg)
@@ -79,7 +82,7 @@ def test_one_train_step(arch):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
+@pytest.mark.parametrize("arch", ARCHS)
 def test_one_decode_step(arch):
     cfg, params, toks = _setup(arch)
     if cfg.moe:

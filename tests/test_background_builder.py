@@ -337,6 +337,8 @@ class _FakePool:
     def __init__(self, n_slots):
         self.n_slots = n_slots
         self.slot_nbytes = 1024
+        self.slot_bytes_by_kind = {"ssm": 1024, "kv": 0, "logits": 0,
+                                   "mask": 0}
         self.data_shards = 1
 
 

@@ -1,4 +1,7 @@
-"""Config registry: the 10 assigned architectures + shapes."""
+"""Config registry: the 10 assigned architectures, the benchmark's
+granite-4.0-h-small, and shapes."""
+import dataclasses
+
 import pytest
 
 from repro.configs.archs import ASSIGNED
@@ -25,11 +28,22 @@ EXPECTED = {
                            n_kv_heads=8, d_ff=14336, vocab_size=65536),
     "deepseek-67b": dict(n_layers=95, d_model=8192, n_heads=64,
                          n_kv_heads=8, d_ff=22016, vocab_size=102400),
+    # hf:ibm-granite/granite-4.0-h-small config.json
+    "granite-4.0-h-small": dict(n_layers=40, d_model=4096, n_heads=32,
+                                n_kv_heads=8, head_dim=128, d_ff=768,
+                                vocab_size=100352, tie_embeddings=True,
+                                position_embedding="none",
+                                embedding_multiplier=12.0,
+                                residual_multiplier=0.22,
+                                attention_multiplier=0.0078125,
+                                logits_scaling=16.0),
 }
+# registered beside the assigned ten: served by the benchmark
+REGISTERED = ASSIGNED + ("granite-4.0-h-small",)
 
 # hyperparameters straight from the assignment
 MOE = {"granite-moe-3b-a800m": (40, 8), "mixtral-8x22b": (8, 2),
-       "jamba-v0.1-52b": (16, 2)}
+       "jamba-v0.1-52b": (16, 2), "granite-4.0-h-small": (72, 10)}
 
 
 def test_all_assigned_registered():
@@ -38,7 +52,7 @@ def test_all_assigned_registered():
     assert len(ASSIGNED) == 10
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
+@pytest.mark.parametrize("arch", REGISTERED)
 def test_exact_assigned_hparams(arch):
     cfg = get_config(arch)
     for k, v in EXPECTED[arch].items():
@@ -56,7 +70,7 @@ def test_families_span_six_types():
 @pytest.mark.parametrize("arch,approx_b", [
     ("mamba2-780m", 0.78), ("llama3.2-1b", 1.24), ("mixtral-8x22b", 141.0),
     ("deepseek-67b", 67.0), ("command-r-plus-104b", 104.0),
-    ("jamba-v0.1-52b", 52.0),
+    ("jamba-v0.1-52b", 52.0), ("granite-4.0-h-small", 32.2),
 ])
 def test_param_counts_match_names(arch, approx_b):
     n = get_config(arch).param_count() / 1e9
@@ -70,12 +84,38 @@ def test_active_params_moe():
 
 
 def test_reduced_configs_are_small():
-    for a in ASSIGNED:
+    for a in REGISTERED:
         r = reduced(get_config(a))
         assert r.n_layers == 2 and r.d_model <= 512
         if r.moe:
             assert r.moe.n_experts <= 4
         r.validate()
+
+
+def test_granite_4_h_small_layout():
+    """36 Mamba-2 mixers and 4 NoPE GQA mixers at layers 5, 15, 25, 35;
+    an MoE block on every layer with a shared SwiGLU expert of 1536."""
+    cfg = get_config("granite-4.0-h-small")
+    kinds = cfg.layer_kinds()
+    assert [i for i, k in enumerate(kinds) if k == "attn"] == [5, 15, 25, 35]
+    assert kinds.count("ssm") == 36
+    assert set(cfg.mlp_kinds()) == {"moe"}
+    s = cfg.ssm
+    assert (cfg.n_ssm_heads, s.head_dim, s.d_state, s.conv_width,
+            s.chunk_size) == (128, 64, 128, 4, 256)
+    assert cfg.moe.shared_d_ff == 1536 and cfg.moe.n_router == 72
+
+
+def test_granite_4_h_small_param_count():
+    """32B-A9B: the shared expert and the 72-wide router counted, every
+    layer's experts and the tied 100352-row table once."""
+    cfg = get_config("granite-4.0-h-small")
+    assert 32.1e9 < cfg.param_count() < 32.3e9
+    assert 8.5e9 < cfg.active_param_count() < 9.5e9
+    d = cfg.d_model
+    no_shared = dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, shared_d_ff=0))
+    assert cfg.param_count() - no_shared.param_count() == 40 * 3 * d * 1536
 
 
 def test_shapes():
