@@ -260,6 +260,9 @@ class GatewayStats:
     # (wall-clock ms, so excluded from == like build_slice_max_s)
     patch_install_max_ms: float = dataclasses.field(compare=False,
                                                     default=0.0)
+    # bytes of the engine's weights held rounded to bfloat16 beside the
+    # float32 tree (ServingEngine.rounded_weight_bytes; 0 off the TPU)
+    rounded_weight_bytes: int = 0
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)  # recurses into RolloverStats

@@ -31,6 +31,21 @@ footprint (inject/finalize change buffer shapes, seq-grow and seq→ring,
 so their inputs cannot alias and are not donated — XLA frees them at the
 end of the call anyway). On CPU test meshes donation is a no-op; on TPU
 it is the difference between one decode-cache working set and two.
+
+**The served weights.** Where a float32 matmul at the precision in effect
+is one bfloat16 pass (a TPU at its default precision), the chip rounds
+each float32 weight to bfloat16 before every product, and a program that
+reads the float32 tree repeats that conversion on every call (the slate
+converts all of them before its decode loop). So the engine holds a
+served copy of the tree in which each float32 leaf of ``MATMUL_LEAVES``
+is rounded to bfloat16 once, at construction (and, for the leaves a
+patch replaces, in ``apply_patch``), on the leaf's own sharding. The
+products see exactly the values they saw before; only the conversion
+pass and half the weight bytes are gone. ``params`` stays the float32
+tree the caller handed in. Elsewhere -- on the CPU, under
+``jax.default_matmul_precision("highest")``, or for a bfloat16 tree --
+the programs read ``params`` itself, and a call made under a precision
+other than the one-pass default reads ``params`` too.
 """
 from __future__ import annotations
 
@@ -47,6 +62,20 @@ from repro.configs.base import ModelConfig
 from repro.models.model import (cache_from_prefill, decode_step, extend,
                                 init_cache, prefill)
 from repro.serving.tracing import span
+
+# Parameter leaves (by key) that the served programs read only as an
+# operand of a default-precision matmul: the SSD projections (models/ssm.py),
+# the attention projections (attention.py), the SwiGLU, expert, shared
+# expert and router weights (mlp.py, moe.py), and an untied head's table.
+# Conv taps, biases, norm scales, A_log, D, dt_bias and the embedding
+# table are read otherwise (elementwise, or by a gather) and keep float32.
+MATMUL_LEAVES = frozenset({
+    "wz", "wx", "wB", "wC", "wdt", "out_proj",
+    "wq", "wk", "wv", "wo",
+    "gate", "up", "down", "router", "shared_gate", "shared_up", "shared_down"})
+# values of ``jax_default_matmul_precision`` at which a float32 matmul is
+# one bfloat16 pass on a TPU
+ONE_PASS = (None, "default", "bfloat16", "fastest")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,9 +100,11 @@ class ServingEngine:
         fin = _named_partial(_finalize_impl, cfg=cfg,
                              capacity=scfg.cache_capacity)
         dec = _named_partial(_decode_impl, cfg=cfg)
+        self._platform = _platform(mesh)
         if mesh is None:
             self.data_shards = 1
             self.params = params
+            self.served_params = self._serve(params)
             self._tok_ns = self._row_ns = self._seq_ns = self._ring_ns = None
             self._prefill = jax.jit(pf)
             self._inject = self._inject_fb = jax.jit(inj)
@@ -100,6 +131,7 @@ class ServingEngine:
         # Parameters move to their sharded layout ONCE, here — every jit
         # below then sees them already placed (no per-call transfer).
         self.params = jax.device_put(params, p_ns)
+        self.served_params = self._serve(self.params)
         # in_shardings double as device_put: numpy panes from pad_tokens
         # and host-assembled cache states get scattered to the mesh at the
         # call boundary; out_shardings pin the returned caches to the same
@@ -124,6 +156,45 @@ class ServingEngine:
         self._decode = jax.jit(
             dec, in_shardings=(p_ns, ring_ns, tok_ns, row_ns),
             out_shardings=(lg_ns, ring_ns), donate_argnums=(1,))
+
+    # ------------------------------------------------------------------
+    @property
+    def rounded_weight_bytes(self) -> int:
+        """Bytes held by the served copy's rounded (bfloat16) leaves: 0
+        where the programs read ``params`` itself."""
+        return sum(int(s.nbytes) for s, p in zip(
+            jax.tree.leaves(self.served_params),
+            jax.tree.leaves(self.params)) if s is not p)
+
+    def _serve(self, params, patched=()):
+        """The tree the programs read at one-pass precision: ``params``
+        with each float32 leaf of ``MATMUL_LEAVES`` rounded to bfloat16, by
+        one program, on the leaf's own sharding; ``params`` itself where
+        the chip does not round float32 products or nothing is float32.
+        ``patched`` (indices of leaves of ``params``) rounds only those
+        leaves again and keeps the rest of the current copy."""
+        if not patched and not _one_pass(self._platform):
+            return params
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        leaves = [x for _, x in flat]
+        if patched:
+            old = jax.tree.leaves(self.served_params)
+            leaves = [x if i in patched else old[i]
+                      for i, x in enumerate(leaves)]
+        todo = [i for i, (path, x) in enumerate(flat)
+                if (not patched or i in patched) and x.dtype == jnp.float32
+                and _matmul_only(path)]
+        if not todo and not patched:
+            return params
+        for i, y in zip(todo, _rounded([leaves[i] for i in todo])):
+            leaves[i] = y
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    def _weights(self):
+        """The served copy where the precision in effect rounds float32
+        products to bfloat16, else the float32 ``params``."""
+        return (self.served_params if _one_pass(self._platform)
+                else self.params)
 
     # ------------------------------------------------------------------
     def prefill_state_shapes(self) -> Tuple[Any, Any]:
@@ -156,10 +227,13 @@ class ServingEngine:
         that rebind is safe (between panes). Shapes and dtypes must match
         the current tree exactly: the jitted entry points were traced
         against them, and a silent mismatch would mean recompilation (or
-        wrong math) mid-serving. Returns the number of leaves patched.
+        wrong math) mid-serving. The served copy rounds the patched
+        matmul leaves again, and only those. Returns the number of leaves
+        patched.
         """
         if not leaves:
             return 0
+        rounds = self.served_params is not self.params
         flat, treedef = jax.tree_util.tree_flatten_with_path(self.params)
         by_path = {jax.tree_util.keystr(p): i for i, (p, _) in
                    enumerate(flat)}
@@ -184,6 +258,9 @@ class ServingEngine:
             new_leaves[i] = (jax.device_put(arr, ns_leaves[i])
                             if ns_leaves is not None else arr)
         self.params = jax.tree_util.tree_unflatten(treedef, new_leaves)
+        self.served_params = (
+            self._serve(self.params, patched={by_path[k] for k in leaves})
+            if rounds else self.params)
         return len(leaves)
 
     # ------------------------------------------------------------------
@@ -241,7 +318,7 @@ class ServingEngine:
         with span("repro.engine.prefill"):
             tokens = self._place(jnp.asarray(tokens), self._tok_ns)
             valid = self._place(jnp.asarray(valid), self._tok_ns)
-            logits, caches = self._prefill(self.params, tokens, valid)
+            logits, caches = self._prefill(self._weights(), tokens, valid)
             b, s = tokens.shape
             return {"caches": caches, "valid": valid,
                     # right-aligned prefill: every row's next position is S
@@ -264,7 +341,7 @@ class ServingEngine:
         the result also carries ``first_logits``: last-valid scores for
         rows with a real suffix, the fallback for empty rows."""
         with span("repro.engine.inject"):
-            args = [self.params,
+            args = [self._weights(),
                     self._place(state["caches"], self._seq_ns),
                     self._place(jnp.asarray(suffix_tokens), self._tok_ns),
                     self._place(jnp.asarray(suffix_valid), self._tok_ns),
@@ -288,7 +365,7 @@ class ServingEngine:
     def decode(self, dec: Dict[str, Any], tokens) -> Tuple[jnp.ndarray, Dict]:
         """One serve step: tokens (B,1) -> (logits (B,Vp), updated dec)."""
         logits, caches = self._decode(
-            self.params,
+            self._weights(),
             self._place(dec["caches"], self._ring_ns),
             self._place(jnp.asarray(tokens), self._tok_ns),
             self._place(dec["pos"], self._row_ns))
@@ -349,12 +426,12 @@ class ServingEngine:
                 self._slate_fns[key] = fn
             first = self._place(jnp.asarray(first_logits), self._tok_ns)
             if row_lens is None:
-                slate = fn(self.params, dec["caches"], dec["pos"], first)
+                slate = fn(self._weights(), dec["caches"], dec["pos"], first)
             else:
                 lens = self._place(jnp.asarray(row_lens, jnp.int32),
                                    self._row_ns)
-                slate = fn(self.params, dec["caches"], dec["pos"], first,
-                           lens)
+                slate = fn(self._weights(), dec["caches"], dec["pos"],
+                           first, lens)
         pending = PendingSlate(slate)
         return np.asarray(pending) if wait else pending
 
@@ -388,6 +465,39 @@ class PendingSlate:
 # ----------------------------------------------------------------------
 # jit bodies (pure functions of pytrees + static cfg)
 # ----------------------------------------------------------------------
+
+def _platform(mesh: Optional[Mesh]) -> str:
+    """The platform the engine's programs run on."""
+    dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    return dev.platform
+
+
+def _one_pass(platform: str) -> bool:
+    """Whether a float32 matmul at the precision in effect is one
+    bfloat16 pass on ``platform``: a TPU's default precision."""
+    return (platform == "tpu" and
+            jax.config.jax_default_matmul_precision in ONE_PASS)
+
+
+def _matmul_only(path) -> bool:
+    """Whether the leaf at ``path`` is one of ``MATMUL_LEAVES``, or an
+    untied head's table (the tied one is also the embedding lookup's)."""
+    keys = [getattr(k, "key", None) for k in path]
+    return keys[-1] in MATMUL_LEAVES or keys[-2:] == ["lm_head", "table"]
+
+
+def _to_bf16(xs):
+    return [x.astype(jnp.bfloat16) for x in xs]
+
+
+def _rounded(xs):
+    """bfloat16 copies of the arrays ``xs`` by one program, each on the
+    sharding of the array it copies."""
+    if not xs:
+        return []
+    return jax.jit(_to_bf16, out_shardings=[
+        getattr(x, "sharding", None) for x in xs])(xs)
+
 
 def _named_partial(fn, **static):
     """``fn`` with its static arguments bound, keeping ``fn``'s name: XLA

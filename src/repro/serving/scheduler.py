@@ -1829,6 +1829,7 @@ class Gateway:
             model_version=self._model_version,
             patches_applied=self._patches_applied,
             patch_install_max_ms=self._patch_install_max_s * 1e3,
+            rounded_weight_bytes=self.engine.rounded_weight_bytes,
         )
 
 

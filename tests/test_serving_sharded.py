@@ -28,6 +28,7 @@ from repro.core.injection import FeatureInjector, InjectionConfig
 from repro.core.realtime import RealtimeConfig, RealtimeFeatureService
 from repro.launch.mesh import make_serving_mesh
 from repro.models.model import init_params, param_shapes, prefill
+from repro.serving import engine as engine_mod
 from repro.serving.engine import ServingConfig, ServingEngine
 from repro.serving.loop import InjectionServer, PrefillStateCache, ServerConfig
 from repro.sharding.rules import seq_cache_pspecs, serving_pspecs
@@ -88,6 +89,29 @@ def test_mesh_1x1_bitwise_equals_plain_engine():
         np.testing.assert_array_equal(rp.slate, rs.slate)
         now += 200
     assert sharded.cache.hits > 0
+
+
+def test_rounded_copy_keeps_the_params_shardings():
+    """With the served copy engaged (the platform described as a TPU),
+    every leaf of the copy on the mesh has its param's sharding, the
+    matmul leaves in bfloat16, and the mesh engine serves what the
+    plain one does."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "_platform", lambda mesh: "tpu")
+        sharded = ServingEngine(_CFG, _PARAMS, _SCFG,
+                                mesh=make_serving_mesh(1, 1))
+        plain = ServingEngine(_CFG, _PARAMS, _SCFG)
+    served = jax.tree_util.tree_flatten_with_path(sharded.served_params)[0]
+    for (path, s), p in zip(served, jax.tree.leaves(sharded.params)):
+        assert s.sharding == p.sharding, path
+        assert s.dtype == (jnp.bfloat16 if path[-1].key in
+                           ("wq", "wk", "wv", "wo", "gate", "up", "down")
+                           else jnp.float32), path
+    assert sharded.rounded_weight_bytes == plain.rounded_weight_bytes > 0
+    toks, valid = plain.pad_tokens([[5, 7, 9], [11, 12]], 32)
+    np.testing.assert_array_equal(
+        np.asarray(sharded.prefill(toks, valid)["logits"]),
+        np.asarray(plain.prefill(toks, valid)["logits"]))
 
 
 def test_mesh_engine_rejects_uneven_batch():
